@@ -32,13 +32,13 @@ __all__ = [
     "collective_axis_names",
 ]
 
-# Primitives that imply a host round-trip or callback. ``name`` match is
-# deliberate — primitives are registered by name and stable across the
-# jax versions we support.
+# Primitives that imply a host round-trip or callback, matched by the
+# name the installed jax registers them under.
 CALLBACK_PRIMITIVES = frozenset({
     "pure_callback",
     "io_callback",
     "debug_callback",
+    "debug_print",
     "callback",
     "outside_call",        # legacy host_callback
     "host_local_array_to_global_array",
@@ -186,7 +186,7 @@ def audit_jaxpr(closed: Any, spec: AuditSpec, *, where: str) -> List[Finding]:
                         f"path declares axes {declared}: the op would bind "
                         f"to an undeclared mesh axis."))
 
-        if prim == "pjit" and eqn.params.get("name") in donating_seen:
+        if prim == "jit" and eqn.params.get("name") in donating_seen:
             donating_seen[eqn.params["name"]] = eqn
 
     if len(callbacks) > spec.allow_callbacks:

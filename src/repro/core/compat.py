@@ -1,10 +1,6 @@
-"""Version compatibility for the handful of jax APIs whose spelling moved.
-
-The repo targets current jax (``jax.shard_map``, ``check_vma``,
-``jax.make_mesh(..., axis_types=...)``, ``jax.lax.axis_size``) but must also
-run on the 0.4.x line where those live under older names.  Everything that
-touches a mesh goes through these three wrappers so the rest of the codebase
-can use one spelling.
+"""The mesh wrappers every module uses, so the rest of the codebase has one
+spelling for replication-unchecked ``shard_map``, Auto-typed meshes and
+static axis sizes.
 """
 from __future__ import annotations
 
@@ -12,65 +8,23 @@ from typing import Any, Sequence
 
 import jax
 
-__all__ = ["shard_map", "make_mesh", "axis_size", "tpu_compiler_params"]
-
-
-def tpu_compiler_params(**kwargs):
-    """Pallas-TPU compiler params; current jax spells the class
-    ``pltpu.CompilerParams``, 0.4.x spells it ``pltpu.TPUCompilerParams``."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
-
-
-_SHARD_MAP_IMPL = None  # (callable, check_kwarg_name), resolved lazily once
-
-
-def _resolve_shard_map():
-    global _SHARD_MAP_IMPL
-    if _SHARD_MAP_IMPL is None:
-        import inspect
-
-        sm = getattr(jax, "shard_map", None)
-        if sm is None:
-            from jax.experimental.shard_map import shard_map as sm
-        # the public promotion (jax.shard_map) and the flag rename
-        # (check_rep -> check_vma) landed in different releases, so feature-
-        # test the signature instead of inferring one from the other
-        flag = ("check_vma" if "check_vma" in inspect.signature(sm).parameters
-                else "check_rep")
-        _SHARD_MAP_IMPL = (sm, flag)
-    return _SHARD_MAP_IMPL
+__all__ = ["shard_map", "make_mesh", "axis_size"]
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
     """``jax.shard_map`` with replication checking off (our collectives
-    intentionally produce replicated outputs from sharded inputs).
-
-    Current jax spells the flag ``check_vma``; older lines spell it
-    ``check_rep`` and may keep shard_map under ``jax.experimental`` — both
-    moves are feature-tested independently.
-    """
-    sm, flag = _resolve_shard_map()
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **{flag: False})
+    intentionally produce replicated outputs from sharded inputs)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]) -> Any:
-    """``jax.make_mesh`` with explicit-collective (Auto) axis types where the
-    installed jax supports them; plain mesh otherwise."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            tuple(axis_shapes), tuple(axis_names),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(axis_names)))
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
+    """``jax.make_mesh`` with explicit-collective (Auto) axis types."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(axis_names)))
 
 
 def axis_size(name: str) -> int:
     """Static size of a named mesh axis, callable inside a shard_map body."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    from jax._src.core import get_axis_env
-
-    return get_axis_env().axis_size(name)
+    return jax.lax.axis_size(name)

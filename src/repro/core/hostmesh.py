@@ -82,13 +82,10 @@ def initialize(coordinator: str, num_hosts: int, host_id: int) -> HostInfo:
     """
     if num_hosts < 2:
         return HostInfo(host_id=0, num_hosts=1)
-    try:
-        # CPU cross-process collectives need the gloo implementation; it
-        # must be selected before the backend initializes.  TPU/GPU ignore
-        # it in favor of the native interconnect.
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # pragma: no cover - newer jax always has the option
-        pass
+    # CPU cross-process collectives need the gloo implementation; it must
+    # be selected before the backend initializes.  TPU/GPU ignore it in
+    # favor of the native interconnect.
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=int(num_hosts),
                                process_id=int(host_id))

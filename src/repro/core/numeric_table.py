@@ -87,7 +87,10 @@ class MLNumericTable:
     def from_numpy(cls, array: np.ndarray, num_shards: Optional[int] = None,
                    mesh: Optional[Mesh] = None,
                    names: Optional[Sequence[Optional[str]]] = None) -> "MLNumericTable":
-        arr = jnp.asarray(array)
+        # on a mesh the host rows go straight to their shards: staging the
+        # whole table on the default device first would cap it at one
+        # device's memory
+        arr = np.asarray(array) if mesh is not None else jnp.asarray(array)
         if mesh is None and num_shards is None:
             num_shards = 1
         return cls(arr, num_shards=num_shards or 1, mesh=mesh, names=names)

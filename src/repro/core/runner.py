@@ -312,40 +312,51 @@ class DistributedRunner:
         early-stopping rungs) keep lr decay and rotating slices monotone
         across segments.
         """
-        upd: UpdateFn = update or _default_update
-        rounds = jnp.asarray(np.arange(start_round,
-                                       start_round + num_rounds,
-                                       dtype=np.int32))
-        donate_argnums = (0,) if self.donate else ()
+        run = self.rounds_fn(local_step, num_rounds, combine=combine,
+                             update=update, start_round=start_round)
         init_state = self._canonical_state(init_state)
         if self.donate:
             # donate a private copy, never the caller's buffer: init_state is
             # typically a params field (w_init) the caller may reuse
             init_state = jax.tree.map(jnp.copy, init_state)
+        return run(init_state, table.data)
+
+    def rounds_fn(self, local_step: LocalStep, num_rounds: int, *,
+                  combine: str = "mean", update: Optional[UpdateFn] = None,
+                  start_round: int = 0) -> Callable:
+        """The jitted program :meth:`run_rounds` drives, ``(state0, data)
+        -> final state``, with the state carry donated when ``donate`` is
+        on.  The table is an argument, never a closed-over constant, so
+        the compiled program does not embed it; public so a caller can
+        lower and compile it from shapes alone."""
+        upd: UpdateFn = update or _default_update
+        rounds = jnp.asarray(np.arange(start_round,
+                                       start_round + num_rounds,
+                                       dtype=np.int32))
+        donate_argnums = (0,) if self.donate else ()
 
         if self.mesh is not None:
             axes = self.data_axes
-            data = table.data
-
-            def round_body(state, r):
-                def spmd(block, state):
-                    part = local_step(block, state, r)
-                    return _COMBINERS[combine](part, axes, self.schedule)
-
-                combined = shard_map(
-                    spmd,
-                    mesh=self.mesh,
-                    in_specs=(pt.data_spec(axes), P()),
-                    out_specs=P(),
-                )(data, state)
-                return upd(state, combined, r), None
 
             @partial(jax.jit, donate_argnums=donate_argnums)
-            def run(state0):
+            def run(state0, data):
+                def round_body(state, r):
+                    def spmd(block, state):
+                        part = local_step(block, state, r)
+                        return _COMBINERS[combine](part, axes, self.schedule)
+
+                    combined = shard_map(
+                        spmd,
+                        mesh=self.mesh,
+                        in_specs=(pt.data_spec(axes), P()),
+                        out_specs=P(),
+                    )(data, state)
+                    return upd(state, combined, r), None
+
                 final, _ = jax.lax.scan(round_body, state0, rounds)
                 return final
 
-            return run(init_state)
+            return run
 
         num_shards = self.num_shards
 
@@ -361,7 +372,7 @@ class DistributedRunner:
             final, _ = jax.lax.scan(round_body, state0, rounds)
             return final
 
-        return run(init_state, table.data)
+        return run
 
     # ------------------------------------------------------------------ #
     # streaming mode: epochs over minibatch windows (beyond the paper)
@@ -382,7 +393,7 @@ class DistributedRunner:
         without one canonical input sharding the jitted epoch compiles
         TWICE for the same shapes — the exact miss
         ``repro.analysis.assert_no_retrace`` flags on the rung loop."""
-        if self.mesh is None or not jax.core.trace_state_clean():
+        if self.mesh is None or not jax.core.trace_ctx.is_top_level():
             # placement is a host-side concern; under an outer trace the
             # caller governs placement and a staged device_put would read
             # as a per-step transfer in the jaxpr audit

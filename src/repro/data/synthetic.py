@@ -36,15 +36,27 @@ def synth_classification(n: int, d: int, seed: int = 0, noise: float = 0.05
     return X, y, w.astype(np.float32)
 
 
-def synth_imagenet_features(n: int, d: int = 4096, seed: int = 0
+def synth_imagenet_features(n: int, d: int = 4096, seed: int = 0,
+                            dtype=np.float64
                             ) -> Tuple[np.ndarray, np.ndarray]:
     """Dense featurized-image stand-in (paper used d=160K; tests scale d
     down).  Features are ReLU'd gaussians (non-negative, sparse-ish like
-    conv features); labels from a planted linear model."""
+    conv features); labels from a planted linear model.
+
+    ``dtype`` is the precision the gaussians are drawn in; the returned
+    ``X`` is float32 either way.  ``np.float32`` draws other values from
+    the same seed but never builds an (n, d) float64 intermediate, which
+    at the paper's width is twice the table."""
     rng = np.random.default_rng(seed)
-    X = np.maximum(rng.normal(size=(n, d)), 0).astype(np.float32)
-    w = rng.normal(size=d) / np.sqrt(d)
-    y = ((X @ w) > np.median(X @ w)).astype(np.float32)
+    if np.dtype(dtype) == np.float32:
+        X = rng.standard_normal(size=(n, d), dtype=np.float32)
+        np.maximum(X, 0, out=X)
+        w = rng.standard_normal(size=d, dtype=np.float32) / np.float32(np.sqrt(d))
+    else:
+        X = np.maximum(rng.normal(size=(n, d)), 0).astype(np.float32)
+        w = rng.normal(size=d) / np.sqrt(d)
+    margin = X @ w
+    y = (margin > np.median(margin)).astype(np.float32)
     return X, y
 
 

@@ -29,8 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
-
 __all__ = ["flash_attention"]
 
 NEG_INF = -2.0e38
@@ -137,7 +135,7 @@ def flash_attention(
             pltpu.VMEM((bq, 1), jnp.float32),   # running denom l
             pltpu.VMEM((bq, hd), jnp.float32),  # output accumulator
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(

@@ -29,8 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
-
 __all__ = ["kmeans_assign_pallas"]
 
 
@@ -83,7 +81,7 @@ def kmeans_assign_pallas(X, C, *, block_rows=256, block_cols=512,
         out_specs=pl.BlockSpec((br, 1), lambda ri, ci: (ri, 0)),
         out_shape=jax.ShapeDtypeStruct((n, 1), jnp.int32),
         scratch_shapes=[pltpu.VMEM((br, k), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(X, ct, cn)

@@ -32,8 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
-
 __all__ = ["logreg_margin", "logreg_xt_z", "logreg_grad_pallas"]
 
 
@@ -93,7 +91,7 @@ def logreg_margin(X, y, w, *, block_rows=256, block_cols=512, interpret=False):
         out_specs=pl.BlockSpec((br, 1), lambda ri, ci: (ri, 0)),
         out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((br, 1), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(X, w.reshape(d, 1), y.reshape(n, 1))
@@ -118,7 +116,7 @@ def logreg_xt_z(X, z, *, block_rows=256, block_cols=512, interpret=False):
         out_specs=pl.BlockSpec((bc, 1), lambda ci, ri: (ci, 0)),
         out_shape=jax.ShapeDtypeStruct((d, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bc, 1), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(X, z.reshape(n, 1))

@@ -1,9 +1,13 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-Each wrapper validates shapes, auto-selects ``interpret=True`` off-TPU (this
-container is CPU-only; the TPU is the deployment target), and falls back to
-the pure-jnp oracle for shapes the kernels' block constraints cannot tile
-(non-divisible sequence lengths etc.) so callers never have to branch.
+Each wrapper validates shapes and auto-selects ``interpret=True`` off-TPU.
+For shapes the kernels' blocks cannot tile (non-divisible sequence lengths,
+the paper's 160,000 features against 512-wide blocks) the wrappers differ by
+backend.  Off-TPU they return the pure-jnp oracle, so callers never have to
+branch.  On TPU they never leave the kernel path in silence: where zero
+padding is exact (the logistic-regression gradient, k-means assignment, the
+int8 matmul) they pad to the tile and slice the result; elsewhere (flash
+attention, the SSD scan) they raise.
 """
 from __future__ import annotations
 
@@ -34,6 +38,23 @@ def _interp() -> bool:
     return not on_tpu()
 
 
+def _pad_to(x: jnp.ndarray, axis: int, multiple: int) -> jnp.ndarray:
+    """Zero-pad ``x`` along ``axis`` up to a multiple of ``multiple``."""
+    extra = -x.shape[axis] % multiple
+    if not extra:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, extra)
+    return jnp.pad(x, widths)
+
+
+def _untileable_on_tpu(name: str, detail: str) -> None:
+    if on_tpu():
+        raise ValueError(f"{name}: {detail} does not tile the kernel's blocks "
+                         f"and has no exact padding; no reference fallback "
+                         f"runs on TPU")
+
+
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None, chunk: Optional[int] = None,
                     scale: Optional[float] = None, block_q: int = 128,
@@ -48,6 +69,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
     bq = min(block_q, Sq)
     bk = min(block_k, Sk)
     if Sq % bq or Sk % bk:
+        _untileable_on_tpu("flash_attention", f"Sq={Sq}, Sk={Sk}")
         return ref.flash_attention_ref(q, k, v, causal=causal, window=window,
                                        chunk=chunk, scale=scale)
     return _flash(q, k, v, causal=causal, window=window, chunk=chunk,
@@ -64,7 +86,12 @@ def kmeans_assign(X, C, *, block_rows: int = 256,
     br = min(block_rows, n)
     bc = min(block_cols, d)
     if n % br or d % bc:
-        return ref.kmeans_assign_ref(X, C)
+        if not on_tpu():
+            return ref.kmeans_assign_ref(X, C)
+        # zero columns add nothing to any distance; padded rows are dropped
+        Xp = _pad_to(_pad_to(X, 0, br), 1, bc)
+        return kmeans_assign_pallas(Xp, _pad_to(C, 1, bc), block_rows=br,
+                                    block_cols=bc, interpret=_interp())[:n]
     return kmeans_assign_pallas(X, C, block_rows=br, block_cols=bc,
                                 interpret=_interp())
 
@@ -77,7 +104,13 @@ def logreg_grad(X, y, w, *, block_rows: int = 256, block_cols: int = 512) -> jnp
     br = min(block_rows, n)
     bc = min(block_cols, d)
     if n % br or d % bc:
-        return ref.logreg_grad_ref(X, y, w)
+        if not on_tpu():
+            return ref.logreg_grad_ref(X, y, w)
+        # zero rows and columns add nothing to the margins or the gradient
+        g = logreg_grad_pallas(_pad_to(_pad_to(X, 0, br), 1, bc),
+                               _pad_to(y, 0, br), _pad_to(w, 0, bc),
+                               block_rows=br, block_cols=bc, interpret=_interp())
+        return g[:d]
     return logreg_grad_pallas(X, y, w, block_rows=br, block_cols=bc,
                               interpret=_interp())
 
@@ -99,8 +132,9 @@ def quant_matmul(xq, x_scale, wq, w_scale, *, block_m: int = 256,
     xq: (M, K) int8, x_scale: (M,), wq: (K, N) int8, w_scale: (N,) → (M, N)
     fp32 equal to ``(xq·wq) * x_scale[:,None] * w_scale[None,:]``.
 
-    On TPU with tilable shapes this is the Pallas kernel (int32 MXU
-    accumulation, bit-exact vs ``ref.quant_matmul_ref``).  Everywhere else —
+    On TPU this is the Pallas kernel (int32 MXU accumulation, bit-exact vs
+    ``ref.quant_matmul_ref``), with shapes that do not tile zero-padded
+    (zero rows of ``wq`` add nothing to the int32 sums).  Everywhere else —
     including the CPU serving path — the dequantized product is taken in
     fp32, which is mathematically the same sum and exact as long as every
     int32 partial fits an fp32 mantissa (K·127² < 2²⁴, i.e. K ≲ 1000; true
@@ -115,9 +149,12 @@ def quant_matmul(xq, x_scale, wq, w_scale, *, block_m: int = 256,
     bm = min(block_m, M)
     bn = min(block_n, N)
     bk = min(block_k, K)
-    if on_tpu() and not (M % bm or N % bn or K % bk):
-        return quant_matmul_pallas(xq, x_scale, wq, w_scale,
-                                   block_m=bm, block_n=bn, block_k=bk)
+    if on_tpu():
+        out = quant_matmul_pallas(
+            _pad_to(_pad_to(xq, 0, bm), 1, bk), _pad_to(x_scale, 0, bm),
+            _pad_to(_pad_to(wq, 0, bk), 1, bn), _pad_to(w_scale, 0, bn),
+            block_m=bm, block_n=bn, block_k=bk, interpret=_interp())
+        return out[:M, :N]
     acc = jnp.dot(xq.astype(jnp.float32), wq.astype(jnp.float32),
                   preferred_element_type=jnp.float32)
     return (acc * x_scale.astype(jnp.float32)[:, None]
@@ -140,5 +177,6 @@ def ssd_chunk_scan(log_a, dx, Bm, Cm, h0=None, *, chunk: int = 64):
         raise ValueError(f"shape mismatch: log_a{log_a.shape} dx{dx.shape} "
                          f"Bm{Bm.shape}")
     if S % min(chunk, S):
+        _untileable_on_tpu("ssd_chunk_scan", f"S={S} with chunk={chunk}")
         return ref.ssd_chunk_scan_ref(log_a, dx, Bm, Cm, h0, chunk=S)
     return _ssd_scan(log_a, dx, Bm, Cm, h0, chunk=chunk, interpret=_interp())

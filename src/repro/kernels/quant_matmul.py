@@ -32,8 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
-
 __all__ = ["quant_matmul_pallas"]
 
 
@@ -86,7 +84,7 @@ def quant_matmul_pallas(xq, xs, wq, ws, *, block_m=256, block_n=256,
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xq, wq, xs2, ws2)

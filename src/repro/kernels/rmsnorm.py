@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
-
 __all__ = ["rmsnorm_pallas"]
 
 
@@ -49,7 +47,7 @@ def rmsnorm_pallas(x: jnp.ndarray, weight: jnp.ndarray, *, eps: float = 1e-6,
         ],
         out_specs=pl.BlockSpec((br, d), lambda ri: (ri, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x2, weight.reshape(1, d))

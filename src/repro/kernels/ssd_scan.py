@@ -26,9 +26,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
-
 __all__ = ["ssd_chunk_scan"]
+
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def _ssd_kernel(la_ref, dx_ref, b_ref, c_ref, h0_ref, y_ref, hout_ref,
@@ -40,33 +40,40 @@ def _ssd_kernel(la_ref, dx_ref, b_ref, c_ref, h0_ref, y_ref, hout_ref,
     def _init():
         h_ref[...] = h0_ref[0, 0].astype(jnp.float32)
 
-    la = la_ref[0, 0, 0].astype(jnp.float32)          # (L,)
+    la = la_ref[0, 0, 0].astype(jnp.float32)          # (1, L)
     dx = dx_ref[0, 0, 0].astype(jnp.float32)          # (L, P)
     Bc = b_ref[0, 0].astype(jnp.float32)              # (L, N)
     Cc = c_ref[0, 0].astype(jnp.float32)              # (L, N)
     h = h_ref[...]                                    # (P, N)
 
-    cum = jnp.cumsum(la)                              # (L,)
-    # intra-chunk dual (attention-like) term
-    CB = jax.lax.dot_general(Cc, Bc, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)   # (L, L)
-    diff = cum[:, None] - cum[None, :]
-    decay = jnp.exp(jnp.minimum(diff, 0.0))
     t_idx = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
     s_idx = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
-    M = CB * decay * (s_idx <= t_idx)
+    causal = s_idx <= t_idx
+    # inclusive cumsum as a triangular matmul (Mosaic lowers no cumsum),
+    # once as a column and once as a row; HIGHEST keeps it f32-exact
+    tri = causal.astype(jnp.float32)
+    nt = (((1,), (1,)), ((), ()))
+    cum_col = jax.lax.dot_general(tri, la, nt, precision=_EXACT,
+                                  preferred_element_type=jnp.float32)  # (L, 1)
+    cum_row = jax.lax.dot_general(la, tri, nt, precision=_EXACT,
+                                  preferred_element_type=jnp.float32)  # (1, L)
+    total = jnp.sum(la, axis=1, keepdims=True)        # (1, 1)
+    # intra-chunk dual (attention-like) term
+    CB = jax.lax.dot_general(Cc, Bc, nt,
+                             preferred_element_type=jnp.float32)   # (L, L)
+    decay = jnp.exp(jnp.minimum(cum_col - cum_row, 0.0))
+    M = jnp.where(causal, CB * decay, 0.0)
     y_intra = jax.lax.dot_general(M, dx, (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)  # (L, P)
     # inter-chunk: carried state contribution
-    Ch = jax.lax.dot_general(Cc, h, (((1,), (1,)), ((), ())),
+    Ch = jax.lax.dot_general(Cc, h, nt,
                              preferred_element_type=jnp.float32)   # (L, P)
-    y_inter = jnp.exp(cum)[:, None] * Ch
+    y_inter = jnp.exp(cum_col) * Ch
     y_ref[0, 0, 0] = (y_intra + y_inter).astype(y_ref.dtype)
 
     # state update: h' = a_chunk·h + Σ_s w_tail(s)·dx(s)⊗B(s)
-    w_tail = jnp.exp(cum[-1] - cum)                   # (L,)
-    wdx = w_tail[:, None] * dx                        # (L, P)
-    h_new = h * jnp.exp(cum[-1]) + jax.lax.dot_general(
+    wdx = jnp.exp(total - cum_col) * dx               # (L, P)
+    h_new = h * jnp.exp(total) + jax.lax.dot_general(
         wdx, Bc, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     h_ref[...] = h_new
 
@@ -96,7 +103,9 @@ def ssd_chunk_scan(
     if h0 is None:
         h0 = jnp.zeros((B, H, P, N), jnp.float32)
 
-    la_c = log_a.reshape(B, H, C, L)
+    # a singleton row axis makes the block's last two dims (1, L) equal
+    # the array's, which the TPU's (8, 128) tiling rule requires
+    la_c = log_a.reshape(B, H, C, 1, L)
     dx_c = dx.reshape(B, H, C, L, P)
     B_c = Bm.reshape(B, C, L, N)
     C_c = Cm.reshape(B, C, L, N)
@@ -106,7 +115,8 @@ def ssd_chunk_scan(
         kernel,
         grid=(B * H, C),
         in_specs=[
-            pl.BlockSpec((1, 1, 1, L), lambda bh, c: (bh // H, bh % H, c, 0)),
+            pl.BlockSpec((1, 1, 1, 1, L),
+                         lambda bh, c: (bh // H, bh % H, c, 0, 0)),
             pl.BlockSpec((1, 1, 1, L, P),
                          lambda bh, c: (bh // H, bh % H, c, 0, 0)),
             pl.BlockSpec((1, 1, L, N), lambda bh, c: (bh // H, c, 0, 0)),
@@ -123,7 +133,7 @@ def ssd_chunk_scan(
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(la_c, dx_c, B_c, C_c, h0)

@@ -168,6 +168,9 @@ class ElasticController:
                   coordinator: str) -> Dict[str, str]:
         env = dict(os.environ)
         env.update(self.env)
+        # simulated hosts are CPU processes: on a machine with a chip, each
+        # child would otherwise try to take it from the parent and the others
+        env["JAX_PLATFORMS"] = "cpu"
         base_flags = env.get("XLA_FLAGS", "")
         env["XLA_FLAGS"] = (
             f"{base_flags} --xla_force_host_platform_device_count="

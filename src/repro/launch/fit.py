@@ -20,7 +20,7 @@ for a multi-device mesh):
         --epochs 8 --rows-per-epoch 256 --features 16 --chunks-per-epoch 4 \\
         --schedule allreduce --ckpt-dir /tmp/mli-logreg --resume
 
-Multi-host (subprocess-simulated hosts; the same flags drive real pods):
+Multi-host (hosts simulated as CPU subprocesses, never on an accelerator):
 
     # BSP: one global 2x4-device mesh, gloo collectives, lock-step rounds
     PYTHONPATH=src python -m repro.launch.fit --algorithm logreg \\
@@ -58,6 +58,7 @@ from repro.core.compat import make_mesh
 from repro.core.optimizer import MinibatchSGD, MinibatchSGDParameters
 from repro.core.runner import CheckpointPolicy
 from repro.data import BatchIterator
+from repro.launch.compile_cache import enable_compile_cache
 
 ALGORITHMS = ("logreg", "linreg", "kmeans", "pipeline")
 
@@ -178,8 +179,9 @@ def main(argv=None) -> None:
     ap.add_argument("--k", type=int, default=4, help="k-means cluster count")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--hosts", type=int, default=1,
-                    help="spawn N subprocess-simulated hosts (multi-host "
-                         "mesh; BSP unless --staleness is given)")
+                    help="spawn N hosts simulated as CPU subprocesses "
+                         "(multi-host mesh; BSP unless --staleness is "
+                         "given); they never use an accelerator")
     ap.add_argument("--devices-per-host", type=int, default=2)
     ap.add_argument("--staleness", type=int, default=None,
                     help="stale-synchronous lane with this bound (0 = "
@@ -191,6 +193,7 @@ def main(argv=None) -> None:
                     help="shared SSP exchange directory (defaults under "
                          "--ckpt-dir or a fresh temp dir)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.hosts > 1 and "REPRO_HOST_ID" not in os.environ:
         _drive_hosts(args, argv)

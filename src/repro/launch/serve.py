@@ -40,6 +40,7 @@ import jax
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config, get_smoke
+from repro.launch.compile_cache import enable_compile_cache
 
 
 # --------------------------------------------------------------------------- #
@@ -375,6 +376,7 @@ def main() -> None:
     ap.add_argument("--kernel", action="store_true",
                     help="kmeans: route assignment through the Pallas kernel")
     args = ap.parse_args()
+    enable_compile_cache()
 
     rep = run_lm(args) if args.engine == "lm" else run_model(args)
     if args.json:

@@ -20,6 +20,7 @@ import numpy as np
 from repro.checkpoint import latest_step, restore_checkpoint, save_checkpoint
 from repro.configs import ARCH_IDS, get_config, get_smoke
 from repro.data import BatchIterator, SyntheticLMDataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.optim.optimizers import adamw
 from repro.train.step import init_train_state, make_train_step
@@ -39,6 +40,7 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     print(f"arch={cfg.name} layers={cfg.num_layers} d_model={cfg.d_model} "

@@ -44,6 +44,7 @@ import numpy as np
 from repro.core.collectives import CollectiveSchedule
 from repro.core.compat import make_mesh
 from repro.core.numeric_table import MLNumericTable
+from repro.launch.compile_cache import enable_compile_cache
 from repro.tune import (AsyncSuccessiveHalving, MedianStoppingRule,
                         ModelSearch, grid, record_evaluation, sample)
 
@@ -180,6 +181,7 @@ def main(argv=None) -> None:
                     help="fault injection (tests): SIGKILL this process "
                          "after N trials are completed and checkpointed")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.grid:
         configs = grid(parse_space(args.grid))

@@ -109,7 +109,7 @@ class TestJaxprAudit:
                            where="t") == []
 
     def test_f64_promotion_detected(self):
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             def promoting(x):
                 return x.astype(jnp.float64).sum()
 

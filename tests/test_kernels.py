@@ -220,6 +220,47 @@ class TestOpsWrappers:
             np.asarray(ops.kmeans_assign(X, C)),
             np.asarray(ref.kmeans_assign_ref(X, C)))
 
+    @pytest.fixture
+    def on_tpu_interpreted(self, monkeypatch):
+        """The wrappers' TPU branches, with the kernels interpreted."""
+        from repro.kernels import ops
+        monkeypatch.setattr(ops, "on_tpu", lambda: True)
+        monkeypatch.setattr(ops, "_interp", lambda: True)
+        return ops
+
+    def test_tpu_pads_untileable_shapes_exactly(self, on_tpu_interpreted):
+        """On TPU, shapes the blocks cannot tile are zero-padded to the
+        tile, never handed to the reference, with the reference's result."""
+        ops = on_tpu_interpreted
+        X = jnp.abs(_rand((37, 600), jnp.float32))   # 600 cols: 512 blocks
+        y = jnp.asarray(RNG.integers(0, 2, size=37), jnp.float32)
+        w = _rand((600,), jnp.float32) * 0.05
+        np.testing.assert_allclose(
+            np.asarray(ops.logreg_grad(X, y, w)),
+            np.asarray(ref.logreg_grad_ref(X, y, w)), rtol=2e-4, atol=2e-4)
+        Xk = _rand((300, 9), jnp.float32)             # 300 rows: 256 blocks
+        C = _rand((3, 9), jnp.float32)
+        np.testing.assert_array_equal(
+            np.asarray(ops.kmeans_assign(Xk, C)),
+            np.asarray(ref.kmeans_assign_ref(Xk, C)))
+        xq, xs = ops.quantize_rows(_rand((4, 600), jnp.float32))
+        wq = jnp.asarray(RNG.integers(-127, 128, size=(600, 300)), jnp.int8)
+        ws = jnp.abs(_rand((300,), jnp.float32)) + 0.01
+        np.testing.assert_array_equal(
+            np.asarray(ops.quant_matmul(xq, xs, wq, ws)),
+            np.asarray(ref.quant_matmul_ref(xq, xs, wq, ws)))
+
+    def test_tpu_raises_where_padding_is_not_exact(self, on_tpu_interpreted):
+        ops = on_tpu_interpreted
+        q = _rand((1, 2, 200, 64), jnp.float32)       # 200: 128 blocks
+        with pytest.raises(ValueError, match="no reference fallback"):
+            ops.flash_attention(q, q, q)
+        with pytest.raises(ValueError, match="no reference fallback"):
+            ops.ssd_chunk_scan(_rand((1, 2, 100), jnp.float32),
+                               _rand((1, 2, 100, 8), jnp.float32),
+                               _rand((1, 100, 4), jnp.float32),
+                               _rand((1, 100, 4), jnp.float32), chunk=64)
+
 
 class TestSSDChunkScan:
     def _inputs(self, B=2, H=3, S=256, P=16, N=32, seed=0):
