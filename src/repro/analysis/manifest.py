@@ -177,7 +177,7 @@ def _build_resident_rounds() -> AuditTarget:
     w0 = jnp.ones((16, 32), jnp.float32)          # 2 KiB carry
     return AuditTarget(
         trace=lambda: runner.run_rounds(_Table(data), w0, _sgd_step, 3),
-        spec=AuditSpec(expect_donation=("run",), large_bytes=1 << 10))
+        spec=AuditSpec(expect_donation=("mli_rounds",), large_bytes=1 << 10))
 
 
 @register("runner.streaming_epoch",
@@ -342,7 +342,7 @@ def _build_mesh_allreduce() -> AuditTarget:
     return AuditTarget(
         trace=lambda: runner.run_rounds(_Table(data), w0, _sgd_step, 2),
         spec=AuditSpec(declared_axes=frozenset({"data"}),
-                       expect_donation=("run",), large_bytes=1 << 10))
+                       expect_donation=("mli_rounds",), large_bytes=1 << 10))
 
 
 @register("mesh.gather_broadcast_epoch",

@@ -1,28 +1,30 @@
 """Retrace sentinel: turn "a warmed hot loop never recompiles" into an assert.
 
-jax emits monitoring events for every backend compilation
-(``/jax/core/compile/backend_compile_duration`` fires exactly once per
-XLA compile; a cache hit emits nothing).  This module installs a pair of
-process-wide listeners — once, lazily — and exposes:
+jax emits ``/jax/core/compile/backend_compile_duration`` once for every
+program it builds: each call of ``compile_or_get_cached``, which is an XLA
+compile or a load of the executable from the persistent compilation cache.
+Only a call that hits a jit's in-memory cache emits nothing.  So a fresh
+``jax.jit`` of a function already in the persistent cache still counts as a
+build: inside a warmed loop it is the same miss, a program built again.
+The event is counted as ``jax.builds`` by the program's one listener
+(:mod:`repro.core.trace`); this module exposes:
 
 * :func:`watch_compiles` — context manager yielding a :class:`CompileWatch`
-  whose ``.compiles`` counts backend compiles inside the block.
+  whose ``.compiles`` counts program builds inside the block.
 * :func:`assert_no_retrace` — context manager that raises
-  :class:`RetraceError` if more than ``allow`` compiles happen inside the
+  :class:`RetraceError` if more than ``allow`` builds happen inside the
   block.  This is the asserted form of the PR-3 "one compiled epoch
   serves the whole search" and PR-8 "a fixed fleet never compiles
   mid-stream" claims.
 
-Listeners are never unregistered (jax's unregister API is private and
-fragile); instead a stack of active watches receives each event.  The
-listeners themselves are free when no watch is active, so importing this
-module costs nothing on the hot path.
+Builds on every thread count, as the listener sees them all.
 """
 from __future__ import annotations
 
 import contextlib
-import threading
-from typing import Iterator, List
+from typing import Iterator, Optional
+
+from repro.core import trace
 
 __all__ = [
     "CompileWatch",
@@ -31,63 +33,41 @@ __all__ = [
     "watch_compiles",
 ]
 
-# One event per XLA backend compile; cache hits emit no event at all.
-# (A single user-visible trace may legitimately produce several of these
-# — e.g. ``jnp.ones`` compiles its own fill program — which is exactly
-# what we want to count: *any* compile inside a warmed loop is a miss.)
-_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-
-_lock = threading.Lock()
-_watches: List["CompileWatch"] = []
-_installed = False
-
 
 class RetraceError(AssertionError):
     """A region that promised zero recompiles compiled anyway."""
 
 
+def _builds() -> float:
+    return trace.snapshot().get("jax.builds", 0)
+
+
 class CompileWatch:
-    """Counts backend compiles observed while the watch is active."""
+    """Counts program builds observed while the watch is active.  (A
+    single user-visible trace may produce several — ``jnp.ones`` builds its
+    own fill program — which is what a warmed loop must not do.)"""
 
     def __init__(self) -> None:
-        self.compiles = 0
+        self._start = _builds()
+        self._end: Optional[float] = None
 
-    def _record(self) -> None:
-        self.compiles += 1
+    @property
+    def compiles(self) -> int:
+        end = _builds() if self._end is None else self._end
+        return int(end - self._start)
 
-
-def _on_event_duration(event: str, duration: float, **kwargs) -> None:
-    if event != _BACKEND_COMPILE_EVENT:
-        return
-    with _lock:
-        active = list(_watches)
-    for watch in active:
-        watch._record()
-
-
-def _install_listener() -> None:
-    global _installed
-    with _lock:
-        if _installed:
-            return
-        import jax.monitoring
-
-        jax.monitoring.register_event_duration_secs_listener(_on_event_duration)
-        _installed = True
+    def _close(self) -> None:
+        self._end = _builds()
 
 
 @contextlib.contextmanager
 def watch_compiles() -> Iterator[CompileWatch]:
-    """Count jax backend compiles that happen inside the ``with`` block."""
-    _install_listener()
+    """Count jax program builds that happen inside the ``with`` block."""
     watch = CompileWatch()
-    with _lock:
-        _watches.append(watch)
     try:
         yield watch
     finally:
-        with _lock:
-            _watches.remove(watch)
+        watch._close()
 
 
 @contextlib.contextmanager

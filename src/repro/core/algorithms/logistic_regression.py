@@ -20,6 +20,7 @@ from typing import Optional, Union
 import jax
 import jax.numpy as jnp
 
+from repro.core import trace
 from repro.core.collectives import CollectiveSchedule
 from repro.core.interfaces import (
     Model,
@@ -168,24 +169,30 @@ class LogisticRegressionAlgorithm(
     supervised = True
 
     def fit(self, data: MLNumericTable) -> LogisticRegressionModel:
-        p = self.params
-        d = data.num_cols - 1
-        gradient = _make_gradient(p)
-        prox = soft_threshold(p.l1) if p.l1 else None
-        w0 = jnp.zeros((d,), jnp.float32)
+        # the span is opened here, not in a wrapper: one more Python frame
+        # under fit() doubled jax's lowering of the rounds program in the
+        # benchmark's fit loop (25 -> 51 ms per fit on a v5e host; PERF.md)
+        with trace.span("mli.fit"):
+            p = self.params
+            d = data.num_cols - 1
+            gradient = _make_gradient(p)
+            prox = soft_threshold(p.l1) if p.l1 else None
+            w0 = jnp.zeros((d,), jnp.float32)
 
-        if p.solver == "gd":
-            opt = GradientDescent(GradientDescentParameters(
-                w_init=w0, grad=gradient, learning_rate=p.learning_rate,
-                max_iter=p.max_iter, schedule=p.schedule, prox=prox))
-        else:
-            opt = StochasticGradientDescent(StochasticGradientDescentParameters(
-                w_init=w0, grad=gradient, learning_rate=p.learning_rate,
-                max_iter=p.max_iter, schedule=p.schedule,
-                local_batch_size=p.local_batch_size, prox=prox,
-                lr_decay=p.lr_decay))
-        weights = opt.apply(data, None)
-        return LogisticRegressionModel(p, weights)
+            if p.solver == "gd":
+                opt = GradientDescent(GradientDescentParameters(
+                    w_init=w0, grad=gradient, learning_rate=p.learning_rate,
+                    max_iter=p.max_iter, schedule=p.schedule, prox=prox))
+            else:
+                opt = StochasticGradientDescent(
+                    StochasticGradientDescentParameters(
+                        w_init=w0, grad=gradient,
+                        learning_rate=p.learning_rate, max_iter=p.max_iter,
+                        schedule=p.schedule,
+                        local_batch_size=p.local_batch_size, prox=prox,
+                        lr_decay=p.lr_decay))
+            weights = opt.apply(data, None)
+            return LogisticRegressionModel(p, weights)
 
     def rebuild(self, partial) -> LogisticRegressionModel:
         return LogisticRegressionModel(self.params,

@@ -45,6 +45,7 @@ Spark lineage.
 from __future__ import annotations
 
 import dataclasses
+import time
 from functools import partial
 from typing import Any, Callable, Iterator, Optional, Sequence, Tuple, Union
 
@@ -54,6 +55,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import partition as pt
+from repro.core import trace
 from repro.core.compat import shard_map
 from repro.core.collectives import (
     CollectiveSchedule,
@@ -312,14 +314,25 @@ class DistributedRunner:
         early-stopping rungs) keep lr decay and rotating slices monotone
         across segments.
         """
-        run = self.rounds_fn(local_step, num_rounds, combine=combine,
-                             update=update, start_round=start_round)
-        init_state = self._canonical_state(init_state)
-        if self.donate:
-            # donate a private copy, never the caller's buffer: init_state is
-            # typically a params field (w_init) the caller may reuse
-            init_state = jax.tree.map(jnp.copy, init_state)
-        return run(init_state, table.data)
+        with trace.span("mli.runner.prepare"):
+            run = self.rounds_fn(local_step, num_rounds, combine=combine,
+                                 update=update, start_round=start_round)
+            init_state = self._canonical_state(init_state)
+            if self.donate:
+                # donate a private copy, never the caller's buffer: init_state
+                # is typically a params field (w_init) the caller may reuse
+                init_state = jax.tree.map(jnp.copy, init_state)
+        with trace.span("mli.runner.launch"):
+            t0 = time.perf_counter()
+            final = run(init_state, table.data)
+            launch_s = time.perf_counter() - t0
+        # staged into an outer program (make_jaxpr, an outer jit), the call
+        # above launched nothing
+        if jax.core.trace_ctx.is_top_level():
+            trace.count("runner.launches")
+            trace.count("runner.rounds", num_rounds)
+            trace.count("runner.launch_s", launch_s)
+        return final
 
     def rounds_fn(self, local_step: LocalStep, num_rounds: int, *,
                   combine: str = "mean", update: Optional[UpdateFn] = None,
@@ -328,7 +341,8 @@ class DistributedRunner:
         -> final state``, with the state carry donated when ``donate`` is
         on.  The table is an argument, never a closed-over constant, so
         the compiled program does not embed it; public so a caller can
-        lower and compile it from shapes alone."""
+        lower and compile it from shapes alone.  The function is named
+        ``mli_rounds``: its program is ``jit_mli_rounds`` in a profile."""
         upd: UpdateFn = update or _default_update
         rounds = jnp.asarray(np.arange(start_round,
                                        start_round + num_rounds,
@@ -339,7 +353,7 @@ class DistributedRunner:
             axes = self.data_axes
 
             @partial(jax.jit, donate_argnums=donate_argnums)
-            def run(state0, data):
+            def mli_rounds(state0, data):
                 def round_body(state, r):
                     def spmd(block, state):
                         part = local_step(block, state, r)
@@ -356,12 +370,12 @@ class DistributedRunner:
                 final, _ = jax.lax.scan(round_body, state0, rounds)
                 return final
 
-            return run
+            return mli_rounds
 
         num_shards = self.num_shards
 
         @partial(jax.jit, donate_argnums=donate_argnums)
-        def run(state0, data):
+        def mli_rounds(state0, data):
             blocks = pt.partition_rows(data, num_shards)
 
             def round_body(state, r):
@@ -372,7 +386,7 @@ class DistributedRunner:
             final, _ = jax.lax.scan(round_body, state0, rounds)
             return final
 
-        return run
+        return mli_rounds
 
     # ------------------------------------------------------------------ #
     # streaming mode: epochs over minibatch windows (beyond the paper)

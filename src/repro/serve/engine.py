@@ -52,6 +52,17 @@ def _now_zero() -> float:
     return 0.0
 
 
+def serve_scatter(cache, sub_cache, slots: jnp.ndarray):
+    """Drop ``sub_cache`` (batch = len(slots)) into ``cache`` at slot
+    indices ``slots`` along the batch axis (axis 1 — axis 0 is the
+    stacked-periods axis).  Out-of-range slot indices are dropped: the
+    ragged prefill pads its admission wave to a fixed batch with dummy
+    rows routed to slot ``num_slots``."""
+    return jax.tree.map(
+        lambda full, sub: full.at[:, slots].set(sub, mode="drop"),
+        cache, sub_cache)
+
+
 class ServeEngine:
     """Fixed-slot continuous-batching engine over a request stream.
 
@@ -92,13 +103,20 @@ class ServeEngine:
             all(k in ATTENTION_KINDS for k in cfg.pattern)
             and not cfg.num_experts and not cfg.cross_attention
             and not cfg.vision_tokens)
-        self._prefill = jax.jit(
-            lambda p, t, c: self.model.prefill(p, t, c))
-        self._prefill_ragged = jax.jit(
-            lambda p, t, n, c: self.model.prefill_ragged(p, t, n, c))
-        self._decode = jax.jit(
-            lambda p, t, pos, c: self.model.decode_step(p, t, pos, c))
-        self._scatter = jax.jit(self._scatter_impl)
+        # each program is named for the trace: jit_serve_prefill and so on
+        def serve_prefill(p, t, c):
+            return self.model.prefill(p, t, c)
+
+        def serve_prefill_ragged(p, t, n, c):
+            return self.model.prefill_ragged(p, t, n, c)
+
+        def serve_decode(p, t, pos, c):
+            return self.model.decode_step(p, t, pos, c)
+
+        self._prefill = jax.jit(serve_prefill)
+        self._prefill_ragged = jax.jit(serve_prefill_ragged)
+        self._decode = jax.jit(serve_decode)
+        self._scatter = jax.jit(serve_scatter)
         # radix prefix KV cache (serve/prefix_cache.py): admission becomes
         # match → restore cached blocks → prefill the uncached tail only
         self.prefix_cache = prefix_cache
@@ -114,9 +132,11 @@ class ServeEngine:
                     "— greedy streams would not be bit-identical cache-on "
                     "vs off.  Use quantize= for int8 weights instead")
             prefix_cache.bind(self.model, self.max_seq)
-            self._prefill_ragged_start = jax.jit(
-                lambda p, t, n, s, c: self.model.prefill_ragged(
-                    p, t, n, c, start_pos=s))
+
+            def serve_prefill_ragged_start(p, t, n, s, c):
+                return self.model.prefill_ragged(p, t, n, c, start_pos=s)
+
+            self._prefill_ragged_start = jax.jit(serve_prefill_ragged_start)
 
     # ------------------------------------------------------------------ #
     # shared-cache plumbing
@@ -133,17 +153,6 @@ class ServeEngine:
                 cache, shardings_for(cache_axes(self.cfg), cache, self.mesh,
                                      self.rules))
         return cache
-
-    @staticmethod
-    def _scatter_impl(cache, sub_cache, slots: jnp.ndarray):
-        """Drop ``sub_cache`` (batch = len(slots)) into ``cache`` at slot
-        indices ``slots`` along the batch axis (axis 1 — axis 0 is the
-        stacked-periods axis).  Out-of-range slot indices are dropped: the
-        ragged prefill pads its admission wave to a fixed batch with dummy
-        rows routed to slot ``num_slots``."""
-        return jax.tree.map(
-            lambda full, sub: full.at[:, slots].set(sub, mode="drop"),
-            cache, sub_cache)
 
     # ------------------------------------------------------------------ #
     # admission → prefill
