@@ -26,7 +26,8 @@ partial`` for one-shot passes) and delegate everything else here:
 
 Both execution modes of the table are supported transparently: **mesh mode**
 (shard_map over the data axes; collectives lower to real HLO) and
-**emulated mode** (logical partitions on one device; the combine is the
+**emulated mode** (logical partitions on one device, run one after
+another with the program one chip of the mesh runs; the combine is the
 algebraically-equal local reduction).  See ``docs/architecture.md`` for the
 data-flow diagram and ``docs/api.md`` for the full surface.
 
@@ -165,6 +166,20 @@ def _stacked_callback_shim(cb: Callable) -> Callable:
     shim.order = getattr(cb, "order", 10)
     shim.before_epoch = getattr(cb, "before_epoch", False)
     return shim
+
+
+def _map_partitions(fn: Callable, blocks: Any) -> Any:
+    """Run ``fn`` on each partition of ``blocks`` (every leaf has a leading
+    ``(num_shards, ...)`` axis), one partition after another, and stack the
+    results ``(num_shards, ...)``.
+
+    The emulated form of the mesh's one-partition-per-chip pass: a
+    ``lax.map``, so ``fn`` traces and compiles once, with no partition
+    batch dimension, as on one chip of the mesh.  A ``vmap`` here would
+    give every op of ``fn`` that dimension, and XLA then transposes each
+    minibatch the local step reads.
+    """
+    return jax.lax.map(fn, blocks)
 
 
 def _emulated_combine(stacked: Any, combine: str) -> Any:
@@ -332,6 +347,9 @@ class DistributedRunner:
             trace.count("runner.launches")
             trace.count("runner.rounds", num_rounds)
             trace.count("runner.launch_s", launch_s)
+            if self.mesh is None:
+                trace.count("runner.partition_passes",
+                            self.num_shards * num_rounds)
         return final
 
     def rounds_fn(self, local_step: LocalStep, num_rounds: int, *,
@@ -379,7 +397,8 @@ class DistributedRunner:
             blocks = pt.partition_rows(data, num_shards)
 
             def round_body(state, r):
-                parts = jax.vmap(lambda b: local_step(b, state, r))(blocks)
+                parts = _map_partitions(lambda b: local_step(b, state, r),
+                                        blocks)
                 combined = _emulated_combine(parts, combine)
                 return upd(state, combined, r), None
 
@@ -469,8 +488,13 @@ class DistributedRunner:
 
             def body(state, r):
                 c = r % chunks
-                chunk = jax.lax.dynamic_slice_in_dim(blocks, c * cr, cr, axis=1)
-                parts = jax.vmap(lambda b: local_step(b, state, r))(chunk)
+
+                def part(wblock):
+                    block = jax.lax.dynamic_slice_in_dim(wblock, c * cr, cr,
+                                                         axis=0)
+                    return local_step(block, state, r)
+
+                parts = _map_partitions(part, blocks)
                 combined = _emulated_combine(parts, combine)
                 return upd(state, combined, r), None
 

@@ -181,6 +181,96 @@ class TestRunRounds:
 
 
 # --------------------------------------------------------------------------- #
+# emulated partitions run one after another: each partition's local pass is
+# the plain local_step on its own block, as on one chip of a mesh
+# --------------------------------------------------------------------------- #
+def _minibatch_sgd(block, w, r):
+    """A local pass shaped like Fig. A4's: a scan over 4-row minibatches."""
+    lr = 0.5 / (1.0 + r)
+    chunks = block.reshape(-1, 4, block.shape[1])
+
+    def step(w, chunk):
+        x, y = chunk[:, 1:], chunk[:, 0]
+        g = jnp.mean(x * (jax.nn.sigmoid(x @ w) - y)[:, None], axis=0)
+        return w - lr * g, None
+
+    return jax.lax.scan(step, w, chunks)[0]
+
+
+def _gradient_stats(block, w, r):
+    x, y = block[:, 1:], block[:, 0]
+    return {"g": jnp.sum(x * (x @ w - y)[:, None], axis=0),
+            "n": jnp.asarray(block.shape[0], jnp.float32)}
+
+
+def _gradient_update(w, stats, r):
+    return w - 0.1 / (1.0 + r) * stats["g"] / stats["n"]
+
+
+_LOCAL_PASSES = {
+    "mean": (_minibatch_sgd, None),
+    "sum": (_gradient_stats, _gradient_update),
+}
+
+
+def _plain_rounds(blocks, w, local_step, update, combine, rounds):
+    """The runner's contract written as a plain loop: each round applies
+    local_step to every block, then combines and updates in NumPy."""
+    for r in rounds:
+        r = jnp.asarray(r, jnp.int32)
+        parts = [jax.tree.map(np.asarray, local_step(b, w, r)) for b in blocks]
+        if combine == "mean":
+            w = np.mean(np.stack(parts), axis=0)
+        else:
+            summed = jax.tree.map(lambda *xs: np.sum(np.stack(xs), axis=0),
+                                  *parts)
+            w = np.asarray(update(w, summed, r))
+    return w
+
+
+def _assert_rel(got, want, tol=1e-6):
+    err = np.abs(np.asarray(got) - want).max() / np.abs(want).max()
+    assert err <= tol, err
+
+
+@pytest.mark.parametrize("combine", ["mean", "sum"])
+@pytest.mark.parametrize("num_shards", [1, 2, 4])
+def test_emulated_partitions_match_plain_loop(rng, num_shards, combine):
+    """run_rounds and epoch_fn on emulated partitions equal a plain loop
+    over the blocks of partition_rows, and each launch of run_rounds counts
+    one partition pass per partition per round."""
+    from repro.core import trace
+
+    local_step, update = _LOCAL_PASSES[combine]
+    X = np.asarray(rng.normal(size=(32, 4)), np.float32)
+    y = (X @ np.asarray(rng.normal(size=4), np.float32) > 0).astype(np.float32)
+    data = np.concatenate([y[:, None], X], 1)
+    w0 = np.asarray(rng.normal(size=4) * 0.1, np.float32)
+    blocks = list(np.asarray(pt.partition_rows(jnp.asarray(data), num_shards)))
+    t = MLNumericTable.from_numpy(data, num_shards=num_shards)
+    runner = DistributedRunner.for_table(t)
+
+    before = trace.snapshot().get("runner.partition_passes", 0)
+    got = runner.run_rounds(t, jnp.asarray(w0), local_step, 3,
+                            combine=combine, update=update)
+    after = trace.snapshot().get("runner.partition_passes", 0)
+    assert after - before == num_shards * 3
+    _assert_rel(got, _plain_rounds(blocks, w0, local_step, update, combine,
+                                   range(3)))
+
+    # two rounds per window: round r reads chunk r % 2 of every partition
+    epoch = runner.epoch_fn(local_step, update, combine=combine,
+                            chunks_per_epoch=2)
+    rounds = np.arange(4, 6, dtype=np.int32)
+    got = epoch(jnp.asarray(w0), jnp.asarray(data), jnp.asarray(rounds))
+    w, half = w0, blocks[0].shape[0] // 2
+    for r in rounds:
+        chunks = [b[(r % 2) * half:(r % 2 + 1) * half] for b in blocks]
+        w = _plain_rounds(chunks, w, local_step, update, combine, [r])
+    _assert_rel(got, w)
+
+
+# --------------------------------------------------------------------------- #
 # streaming mode (emulated partitions; mesh + kill behavior is covered by
 # tests/test_streaming_resume.py subprocesses)
 # --------------------------------------------------------------------------- #

@@ -14,6 +14,7 @@ that they land on one worker.
 from __future__ import annotations
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -101,6 +102,59 @@ def test_logreg_round_program_fits_one_chip(one_chip):
     table_bytes = LOGREG_ROWS * LOGREG_COLS * 4
     assert compiled.memory_analysis().argument_size_in_bytes >= table_bytes
     assert _footprint(compiled) < V5E_HBM_BYTES
+
+
+_HLO_OP = re.compile(r"= (\w+\[[\d,]*\])(\{[^}]*\})? ([\w-]+)\(")
+
+
+def _minibatch_ops(hlo: str):
+    """The ops of a compiled logreg round program that touch one minibatch:
+    (result shape, layout) of each copy of a 32-row slice of the table, and
+    the result shapes of the reductions the gradient makes of it."""
+    copies, reductions = [], set()
+    for line in hlo.splitlines():
+        m = _HLO_OP.search(line)
+        if m is None:
+            continue
+        shape, layout, opcode = m.groups()
+        name = line.split("=", 1)[0]
+        if opcode == "copy" and shape.endswith(f"{LOGREG_BATCH},{LOGREG_COLS}]"):
+            copies.append((shape, layout))
+        elif opcode == "fusion" and "reduce_fusion" in name:
+            reductions.add(shape)
+    return sorted(copies), reductions
+
+
+def test_logreg_emulated_partitions_compile_as_mesh_chips(topo, one_chip):
+    """Four emulated partitions on one chip run the program one chip of the
+    4-chip mesh runs, once per partition: no op of a minibatch carries a
+    partition dimension (a vmap over partitions made XLA transpose every
+    (4, 32, 160,001) minibatch in HBM), the two reductions of each step
+    make f32[32] margins and an f32[160000] gradient, every copy of a
+    minibatch is one the mesh program makes too, and the program still
+    fits one chip."""
+    from repro.core.runner import DistributedRunner
+
+    local_round = _logreg_local_round()
+    emulated = DistributedRunner(num_shards=LOGREG_PARTITIONS, donate=True)
+    compiled = emulated.rounds_fn(local_round, LOGREG_ROUNDS).lower(
+        _sds((LOGREG_COLS - 1,), np.float32, one_chip),
+        _sds((LOGREG_ROWS, LOGREG_COLS), np.float32, one_chip)).compile()
+    assert _footprint(compiled) < V5E_HBM_BYTES
+    hlo = compiled.as_text()
+    assert f"{LOGREG_PARTITIONS},{LOGREG_BATCH},{LOGREG_COLS}]" not in hlo
+
+    mesh = Mesh(np.asarray(topo.devices), ("data",),
+                axis_types=(jax.sharding.AxisType.Auto,))
+    meshed = DistributedRunner(mesh=mesh, donate=True)
+    mesh_hlo = meshed.rounds_fn(local_round, LOGREG_ROUNDS).lower(
+        _sds((LOGREG_COLS - 1,), np.float32, NamedSharding(mesh, P())),
+        _sds((LOGREG_ROWS * len(topo.devices), LOGREG_COLS), np.float32,
+             NamedSharding(mesh, P("data", None)))).compile().as_text()
+
+    copies, reductions = _minibatch_ops(hlo)
+    assert reductions == {f"f32[{LOGREG_BATCH}]", f"f32[{LOGREG_COLS - 1}]"}
+    assert (copies, reductions) == _minibatch_ops(mesh_hlo)
 
 
 @pytest.mark.parametrize("schedule", ["gather_broadcast", "allreduce",
