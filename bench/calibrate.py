@@ -98,7 +98,7 @@ def serve_readings(cell, seeds, control_seeds, seconds, rates):
     from bench.traffic import serving_requests
 
     cfg, mix = cell.config, dict(cell.traffic)
-    engine = serve.build_engine(cfg, seeds[0])
+    engine, params = serve.build_engine(cfg, seeds[0])
     lens = set()
     for rate in rates or [mix["rate_per_s"]]:
         for seed in seeds:
@@ -108,8 +108,8 @@ def serve_readings(cell, seeds, control_seeds, seconds, rates):
     engine.warmup(prompt_lens=sorted(lens))
     for i, seed in enumerate(seeds):
         if i:
-            engine.params = serve.make_params(seed, engine.cfg,
-                                              cfg["weights"])
+            params = serve.make_params(seed, engine.cfg, cfg["weights"])
+            engine.params = params
         for rate in rates or [mix["rate_per_s"]]:
             plan = serving_requests(dict(mix, rate_per_s=rate), seconds, seed,
                                     cfg["vocab_size"])
@@ -135,13 +135,13 @@ def serve_readings(cell, seeds, control_seeds, seconds, rates):
                                                mix["check_requests"])
                 t1 = time.perf_counter()
                 line["program"] = float(np.max(serve.reference_gaps(
-                    cfg, engine.params, check)))
+                    cfg, params, check)))
                 line["reference_s"] = time.perf_counter() - t1
                 line["checked_tokens"] = sum(len(r.out_tokens) for r in check)
                 if i < control_seeds:
-                    picks = control_tokens(cfg, engine.params, check)
+                    picks = control_tokens(cfg, params, check)
                     line["control"] = float(np.max(serve.reference_gaps(
-                        cfg, engine.params, check, chosen=picks)))
+                        cfg, params, check, chosen=picks)))
             print(json.dumps(line), flush=True)
 
 

@@ -2,19 +2,21 @@
 the traced slice.  The least bytes of one decode step are the weights plus
 the K/V of the positions live in it; at the peak HBM rate that takes
 bytes / peak, over the decode program's device time.  The decode program
-is the one the trace runs once per engine loop turn with the most device
-time."""
+is the engine's ``serve_decode`` jit, ``jit_serve_decode`` in the trace;
+a trace without it reads nothing."""
+
+PROGRAM = "jit_serve_decode"
 
 
 def read(run):
     t = run.trace
-    turns = run.stats.get("decode_steps")
-    if t is None or not turns:
+    if t is None or not run.stats.get("decode_steps"):
         return None
-    per_turn = [n for n, c in t["module_n"].items() if c >= 0.9 * turns]
-    if not per_turn:
+    names = [n for n in t["module_n"] if n.startswith(PROGRAM)]
+    runs = sum(t["module_n"][n] for n in names)
+    device_s = sum(t["module_s"][n] for n in names)
+    if not runs or not device_s:
         return None
-    name = max(per_turn, key=t["module_s"].get)
-    least = (t["module_n"][name] * run.stats["weight_bytes"]
+    least = (runs * run.stats["weight_bytes"]
              + run.stats["live_positions"] * run.stats["kv_bytes_per_position"])
-    return least / run.peaks["hbm_bytes_per_s"] / t["module_s"][name] * 100.0
+    return least / run.peaks["hbm_bytes_per_s"] / device_s * 100.0

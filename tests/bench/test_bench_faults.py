@@ -209,6 +209,54 @@ def test_serving_sound_run_is_correct(tmp_path):
     assert out["correct"] is True, out["checks"]
 
 
+def test_serving_cell_reports_the_metrics_benchmark_json_lists(tmp_path):
+    """The result line holds exactly the cell's end-to-end metrics, and each
+    per-layer reader the cell lists reads a finite value from the driver's
+    own slice counts beside a hand-built trace summary."""
+    import math
+    import types
+
+    from bench.drivers import serve
+    from bench.traffic import serving_requests
+
+    name = "qwen2-1.5b.decode-heavy"
+    root = small_root(tmp_path)
+    spec = json.loads((REPO / "BENCHMARK.json").read_text())
+    out = run(root, name, seconds=2.0, seed=SERVE_SEED)
+    assert set(out["metrics"]) == {
+        m["name"] for m in spec["end_to_end"]
+        if name in m.get("workloads", [name])}
+    assert all(math.isfinite(m["value"]) for m in out["metrics"].values())
+
+    cell = harness.find_cell(name, root)
+    cfg = cell.config
+    engine, params = serve.build_engine(cfg, SERVE_SEED)
+    plan = serving_requests(cell.traffic, 1.0, SERVE_SEED, cfg["vocab_size"])
+    requests, start = serve.serve_window(engine, plan, lambda now: None,
+                                         time.perf_counter)
+    weight_bytes = sum(x.nbytes for x in jax.tree.leaves(params))
+    last = max(r.out_tokens.times[-1] for r in requests)
+    stats = {"slots": engine.batch,
+             **serve._slice_stats(cfg, requests, start, weight_bytes, start,
+                                  last)}
+    turns = stats["decode_steps"]
+    trace = {"module_s": {"jit_serve_decode(1)": 0.5,
+                          "jit_serve_prefill_ragged(2)": 0.2},
+             "module_n": {"jit_serve_decode(1)": turns,
+                          "jit_serve_prefill_ragged(2)": turns},
+             "busy_s": 0.8, "window_s": 1.0}
+    peaks = json.loads((REPO / "bench" / "peaks.json").read_text())
+    view = types.SimpleNamespace(trace=trace, stats=stats, e2e={}, config=cfg,
+                                 traffic=cell.traffic, chips=1,
+                                 peaks=peaks["TPU v5 lite"])
+    readers = [m["name"] for m in spec["per_layer"]
+               if name in m.get("workloads", [name])]
+    assert readers and all(r.startswith("serve.") for r in readers)
+    for reader in readers:
+        value = harness.load_metric(reader, root).read(view)
+        assert value is not None and math.isfinite(value), reader
+
+
 def test_serving_token_altered_where_produced_is_caught(tmp_path,
                                                         monkeypatch):
     _wrap_decode(monkeypatch,
@@ -222,6 +270,25 @@ def test_serving_cache_returned_unchanged_is_caught(tmp_path, monkeypatch):
     _wrap_decode(monkeypatch, lambda out, cache: (out[0], cache))
     out = run(small_root(tmp_path), "qwen2-1.5b.decode-heavy", seconds=2.0,
               seed=SERVE_SEED)
+    assert out["correct"] is False
+
+
+def test_serving_half_of_the_slots_left_out_is_caught(tmp_path, monkeypatch):
+    """The decode step computes every other slot only; each odd slot is
+    handed the token of the even slot before it.  The scheduler fills the
+    lowest free slot first, so odd and even slots serve about as many
+    requests.  Here the check reads every request, so that which slots the
+    sampled ones held, which the host's timing decides, cannot hide it."""
+    def half(out, cache):
+        logits = out[0]
+        return logits.at[1::2].set(logits[0::2]), out[1]
+
+    root = small_root(tmp_path)
+    mix = root / "bench" / "traffic" / "decode-heavy.json"
+    mix.write_text(json.dumps(dict(json.loads(mix.read_text()),
+                                   check_requests=10**6)))
+    _wrap_decode(monkeypatch, half)
+    out = run(root, "qwen2-1.5b.decode-heavy", seconds=2.0, seed=SERVE_SEED)
     assert out["correct"] is False
 
 
@@ -239,13 +306,13 @@ def test_serving_int8_control_reads_far_above_the_program(tmp_path):
     cfg, mix = cell.config, cell.traffic
     sound, control = [], []
     for seed in (1, 2, 3):
-        engine = serve.build_engine(cfg, seed)
+        engine, params = serve.build_engine(cfg, seed)
         plan = serving_requests(mix, 1.0, seed, cfg["vocab_size"])
         requests, _ = serve.serve_window(engine, plan, lambda now: None,
                                          time.perf_counter)
         check = serve.sample_for_check(requests, seed, mix["check_requests"])
-        sound.append(serve.reference_gaps(cfg, engine.params, check).max())
-        picks = calibrate.control_tokens(cfg, engine.params, check)
-        control.append(serve.reference_gaps(cfg, engine.params, check,
+        sound.append(serve.reference_gaps(cfg, params, check).max())
+        picks = calibrate.control_tokens(cfg, params, check)
+        control.append(serve.reference_gaps(cfg, params, check,
                                             chosen=picks).max())
     assert min(control) > 0 and max(control) > 3 * max(sound)

@@ -75,3 +75,33 @@ def test_recorded_v5e_trace():
     # recording: each fit's two programs lie within 2.5 ms before its span
     for start, end, _ in trace.modules[0]:
         assert any(fs - 2.5e6 <= start and end <= fe for fs, fe, _ in fits)
+
+
+def _serving_view(module_s, module_n):
+    import json
+    import types
+
+    peaks = json.loads((HERE.parents[1] / "bench" / "peaks.json").read_text())
+    return types.SimpleNamespace(
+        trace={"module_s": module_s, "module_n": module_n},
+        stats={"decode_steps": 50, "weight_bytes": 3e9,
+               "live_positions": 1_000_000, "kv_bytes_per_position": 28672},
+        chips=1, peaks=peaks["TPU v5 lite"])
+
+
+def test_decode_roofline_reads_the_decode_program_by_name():
+    """A prefill program run as often as the decode step, and with more
+    device time, is not the one read."""
+    from bench.harness import load_metric
+
+    reader = load_metric("serve.decode_hbm_roofline")
+    view = _serving_view(
+        {"jit_serve_prefill_ragged(7)": 9.0, "jit_serve_decode(3)": 2.0,
+         "jit_serve_scatter(4)": 0.1},
+        {"jit_serve_prefill_ragged(7)": 50, "jit_serve_decode(3)": 50,
+         "jit_serve_scatter(4)": 50})
+    least = 50 * 3e9 + 1_000_000 * 28672
+    assert reader.read(view) == pytest.approx(least / 819e9 / 2.0 * 100.0)
+    no_decode = _serving_view({"jit_serve_prefill_ragged(7)": 9.0},
+                              {"jit_serve_prefill_ragged(7)": 50})
+    assert reader.read(no_decode) is None
