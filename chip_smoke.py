@@ -349,8 +349,7 @@ def _prefill_wave_logits(engine, prompts, pad_to: int = 8) -> np.ndarray:
         tokens[i, :len(p)] = p
         lens[i] = len(p)
     logits, _ = engine._prefill_ragged(
-        engine.params, jnp.asarray(tokens), jnp.asarray(lens),
-        engine.model.init_cache(engine.batch, engine.max_seq))
+        engine.params, jnp.asarray(tokens), jnp.asarray(lens))
     return np.asarray(logits[:len(prompts), -1], np.float32)
 
 

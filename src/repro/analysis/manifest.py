@@ -257,11 +257,10 @@ def _build_ragged_prefill() -> AuditTarget:
 
     _, params, engine = _smoke_lm()
     wb, S = 4, 16
-    sub = engine.model.init_cache(wb, engine.max_seq)
     toks = jnp.zeros((wb, S), jnp.int32)
     lens = jnp.full((wb,), S, jnp.int32)
     return AuditTarget(
-        trace=lambda: engine._prefill_ragged(params, toks, lens, sub),
+        trace=lambda: engine._prefill_ragged(params, toks, lens),
         spec=AuditSpec())
 
 

@@ -264,6 +264,8 @@ def model_flops_estimate(cfg: ArchConfig, shape: InputShape) -> float:
         di = cfg.d_inner_ssd
         n += ssd_layers * (2 * d * di + 2 * d * cfg.ssm_state
                            + d * cfg.ssd_heads + di * d)
+        if cfg.mlp_kind != "none":      # granite-4.0-h: a SwiGLU after each
+            n += ssd_layers * 3 * d * cfg.d_ff
     n += cfg.vocab_size * d  # logits matmul (embeddings tied)
     tokens = shape.global_batch * (shape.seq_len if shape.kind != "decode" else 1)
     mult = 6.0 if shape.kind == "train" else 2.0

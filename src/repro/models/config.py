@@ -9,10 +9,12 @@ assigned architectures' layer interleavings:
     llama4 3:1 chunked   pattern=[ATTN_CHUNKED]*3 + [ATTN_NOPE]   (iRoPE)
     recurrentgemma 1:2   pattern=[RGLRU, RGLRU, ATTN_LOCAL]       (Griffin)
     mamba2               pattern=[SSD]
+    granite-4.0-h        pattern=[SSD]*5 + [ATTN_NOPE] + [SSD]*4
     whisper              decoder pattern=[ATTN] + cross-attention, encoder stack
 
-Every block is followed by its MLP (dense or MoE) except SSD/RGLRU blocks,
-which are self-contained (mamba2 has no MLP: d_ff=0).
+Every block is followed by its MLP (dense or MoE) unless ``mlp_kind`` is
+"none" (mamba2 has no MLP: d_ff=0; granite-4.0-h puts a SwiGLU after its
+SSD and attention blocks alike).
 """
 from __future__ import annotations
 
@@ -58,6 +60,7 @@ class ArchConfig:
     window: int = 4096                      # for ATTN_LOCAL
     attn_chunk: int = 8192                  # for ATTN_CHUNKED
     qkv_bias: bool = False                  # qwen-style
+    attn_scale: Optional[float] = None      # softmax scale; None → hd**-0.5
     rope_base: float = 10000.0
     causal: bool = True
 
@@ -95,6 +98,10 @@ class ArchConfig:
     norm_eps: float = 1e-6
     tie_embeddings: bool = True
     max_seq_len: int = 524288
+    # granite-style scalings; 1.0 leaves the computation as it is
+    embedding_multiplier: float = 1.0       # token embeddings times this
+    residual_multiplier: float = 1.0        # every block's branch times this
+    logits_scaling: float = 1.0             # logits divided by this
 
     # numerics & perf knobs
     dtype: str = "bfloat16"                 # activation / param dtype
@@ -133,6 +140,12 @@ class ArchConfig:
         if self.head_dim is not None:
             return self.head_dim
         return self.d_model // self.num_heads
+
+    @property
+    def attention_scale(self) -> float:
+        if self.attn_scale is not None:
+            return self.attn_scale
+        return self.resolved_head_dim ** -0.5
 
     @property
     def num_periods(self) -> int:

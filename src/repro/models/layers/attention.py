@@ -149,7 +149,7 @@ def _prefill_attend(q, k, v, kind: BlockKind, cfg: ArchConfig,
     """q/k/v: (B, S, H, hd) with kv already broadcast to H heads.
     positions: (S,) int32 absolute positions (shared across batch)."""
     B, S, H, hd = q.shape
-    scale = hd ** -0.5
+    scale = cfg.attention_scale
     C = min(cfg.q_chunk, S)
     if S % C != 0:
         C = S  # fall back to single block (configs keep shapes divisible)
@@ -254,7 +254,7 @@ def attn_apply(
             kf = _repeat_kv(k, groups)
             vf = _repeat_kv(v, groups)
             mask = jnp.ones((1, 1, S, k.shape[1]), bool)
-            out = _sdpa(q, kf, vf, mask, hd ** -0.5)
+            out = _sdpa(q, kf, vf, mask, cfg.attention_scale)
             cross_cache = {"k": k, "v": v} if cache is not None else None
             return _out_proj(out, params), cross_cache
         if start_pos is not None:
@@ -309,7 +309,8 @@ def attn_apply(
         kc, vc = _cache_read(cache)
         mask = jnp.ones((1, 1, 1, kc.shape[1]), bool)
         new_cache = cache
-    out = _sdpa(q, _repeat_kv(kc, groups), _repeat_kv(vc, groups), mask, hd ** -0.5)
+    out = _sdpa(q, _repeat_kv(kc, groups), _repeat_kv(vc, groups), mask,
+                cfg.attention_scale)
     return _out_proj(out, params), new_cache
 
 
@@ -326,7 +327,6 @@ def _prefill_offset(params, q, k, v, *, cfg: ArchConfig, kind: BlockKind,
     if cache is None or lengths is None:
         raise ValueError("start_pos= prefill needs cache= and lengths=")
     B, S = q.shape[0], q.shape[1]
-    hd = cfg.resolved_head_dim
     start = jnp.asarray(start_pos, jnp.int32)                  # (B,)
     L = jnp.asarray(lengths, jnp.int32)                        # (B,)
     pos_bq = start[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
@@ -363,7 +363,7 @@ def _prefill_offset(params, q, k, v, *, cfg: ArchConfig, kind: BlockKind,
     k_all = jnp.concatenate([kc.astype(k.dtype), k], axis=1)
     v_all = jnp.concatenate([vc.astype(v.dtype), v], axis=1)
     out = _sdpa(q, _repeat_kv(k_all, groups), _repeat_kv(v_all, groups),
-                mask, hd ** -0.5)
+                mask, cfg.attention_scale)
     cache = _prefill_fill_cache(cache, k, v, lengths, start=start)
     return _out_proj(out, params), cache
 

@@ -17,6 +17,7 @@ Weight layout convention (true for every projection in ``models/layers``):
 the *contracted* axes lead and the *output-channel* axes trail, so
 ``n_contract`` pins the split — wq/wk/wv ``(d | h, hd)`` → n_contract 1,
 wo ``(h, hd | d)`` → 2, mlp w_gate/w_up/w_down ``(d | f)`` / ``(f | d)``
+→ 1, the SSD projections wz/w_xbc/wdt ``(d | ·)`` and w_out ``(di | d)``
 → 1.  ``scale`` has the output-channel (and any stacked) axes only.
 """
 from __future__ import annotations
@@ -92,11 +93,13 @@ def linear_or_quant(x: jnp.ndarray, w, eq: str, **einsum_kwargs) -> jnp.ndarray:
 
 
 # weight name → n_contract, per module; everything absent stays fp32
-# (biases, norms, embeddings, MoE experts, SSD/RG-LRU mixers).
+# (biases, norms, embeddings, MoE experts, the SSD conv and recurrence
+# parameters, RG-LRU mixers).
 _QUANT_SPECS: Dict[str, Dict[str, int]] = {
     "attn": {"wq": 1, "wk": 1, "wv": 1, "wo": 2},
     "cross": {"wq": 1, "wk": 1, "wv": 1, "wo": 2},
     "mlp": {"w_gate": 1, "w_up": 1, "w_down": 1},
+    "ssd": {"wz": 1, "w_xbc": 1, "wdt": 1, "w_out": 1},
 }
 
 
@@ -121,7 +124,7 @@ def quantize_model_params(params: Dict[str, Any], mode: str) -> Dict[str, Any]:
 
     ``"none"`` returns the tree unchanged; ``"bf16"`` casts every floating
     leaf to bfloat16 (weight-only — activations keep the config dtype);
-    ``"int8"`` quantizes the attention/MLP projections per
+    ``"int8"`` quantizes the attention/MLP/SSD projections per
     ``_QUANT_SPECS`` and leaves everything else fp32."""
     if mode == "none":
         return params

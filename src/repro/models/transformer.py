@@ -35,7 +35,11 @@ from repro.models.layers.rglru import init_rglru_cache, rglru_apply, rglru_init
 from repro.models.layers.ssd import init_ssd_cache, ssd_apply, ssd_init
 from repro.models.params import split_tree_of, stack_bundles
 
-__all__ = ["init_model", "TransformerLM"]
+__all__ = ["init_model", "TransformerLM", "RAGGED_KINDS"]
+
+# blocks whose right-padded ragged prefill leaves each row's cache as its
+# unpadded prefill would (``TransformerLM.prefill_ragged``)
+RAGGED_KINDS = ATTENTION_KINDS + (BlockKind.SSD,)
 
 
 def _norm_init(cfg: ArchConfig, dtype):
@@ -46,6 +50,27 @@ def _norm_init(cfg: ArchConfig, dtype):
 def _norm_apply(cfg: ArchConfig, params, x):
     return rmsnorm(params, x, cfg.norm_eps) if cfg.norm_kind == "rmsnorm" \
         else layernorm(params, x, cfg.norm_eps)
+
+
+def _residual(cfg: ArchConfig, x, h):
+    """x plus a block's branch h, scaled by ``residual_multiplier``."""
+    if cfg.residual_multiplier != 1.0:
+        h = h * cfg.residual_multiplier
+    return x + h
+
+
+def _embed(cfg: ArchConfig, params, tokens, positions=None):
+    x = embed_tokens(params["embed"], tokens, positions)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
+
+
+def _logits(cfg: ArchConfig, params, x):
+    logits = logits_from(params["embed"], x)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 # --------------------------------------------------------------------------- #
@@ -74,6 +99,9 @@ def _block_init(key: jax.Array, cfg: ArchConfig, kind: BlockKind, dtype,
             mixed["mlp"] = mlp_init(ks[3], cfg, dtype)
     elif kind == BlockKind.SSD:
         mixed["ssd"] = ssd_init(ks[0], cfg, dtype)
+        if cfg.mlp_kind != "none":
+            mixed["ln2"] = split_tree_of(_norm_init(cfg, dtype))
+            mixed["mlp"] = mlp_init(ks[3], cfg, dtype)
     else:
         raise ValueError(kind)
     params = {k: v[0] for k, v in mixed.items()}
@@ -156,15 +184,14 @@ class TransformerLM:
     def init_cache(self, batch: int, max_seq: int) -> Dict[str, Any]:
         cfg = self.cfg
         dtype = jnp.dtype(cfg.dtype)
-        per_period = []
-        for _ in range(cfg.num_periods):
-            c = {
-                f"b{i}": _block_cache(cfg, kind, batch, max_seq,
-                                      cfg.cross_attention, cfg.encoder_seq, dtype)
-                for i, kind in enumerate(cfg.pattern)
-            }
-            per_period.append(c)
-        return jax.tree.map(lambda *xs: jnp.stack(xs, 0), *per_period)
+        # one period's shapes, then each leaf made once at its stacked shape
+        # (no per-period pieces to stack: a cache is never held twice)
+        period = jax.eval_shape(lambda: {
+            f"b{i}": _block_cache(cfg, kind, batch, max_seq,
+                                  cfg.cross_attention, cfg.encoder_seq, dtype)
+            for i, kind in enumerate(cfg.pattern)})
+        return jax.tree.map(
+            lambda s: jnp.zeros((cfg.num_periods,) + s.shape, s.dtype), period)
 
     # ---------------- encoder ---------------- #
     def encode(self, params: Dict, frames: jnp.ndarray) -> jnp.ndarray:
@@ -210,7 +237,7 @@ class TransformerLM:
                               start_pos=start_pos)
             if c is not None:
                 new_cache["attn"] = c
-            x = x + h
+            x = _residual(cfg, x, h)
             has_cross_cache = cache is not None and "cross" in cache
             if "cross" in bp and (memory is not None or has_cross_cache):
                 h = _norm_apply(cfg, bp["ln_cross"], x)
@@ -220,31 +247,29 @@ class TransformerLM:
                                    kv_src=memory, is_cross=True, use_rope=False)
                 if cc is not None:
                     new_cache["cross"] = cc
-                x = x + h
+                x = _residual(cfg, x, h)
             if "moe" in bp:
                 h = _norm_apply(cfg, bp["ln2"], x)
                 h, aux = moe_apply(bp["moe"], h, cfg)
-                x = x + h
-            elif "mlp" in bp:
-                h = _norm_apply(cfg, bp["ln2"], x)
-                x = x + mlp_apply(bp["mlp"], h)
+                x = _residual(cfg, x, h)
         elif kind == BlockKind.RGLRU:
             h = _norm_apply(cfg, bp["ln1"], x)
             h, c = rglru_apply(bp["rglru"], h, cfg=cfg, mode=mode,
                                cache=None if cache is None else cache.get("rglru"))
             if c is not None:
                 new_cache["rglru"] = c
-            x = x + h
-            if "mlp" in bp:
-                h = _norm_apply(cfg, bp["ln2"], x)
-                x = x + mlp_apply(bp["mlp"], h)
+            x = _residual(cfg, x, h)
         elif kind == BlockKind.SSD:
             h = _norm_apply(cfg, bp["ln1"], x)
             h, c = ssd_apply(bp["ssd"], h, cfg=cfg, mode=mode,
-                             cache=None if cache is None else cache.get("ssd"))
+                             cache=None if cache is None else cache.get("ssd"),
+                             lengths=lengths)
             if c is not None:
                 new_cache["ssd"] = c
-            x = x + h
+            x = _residual(cfg, x, h)
+        if "mlp" in bp:
+            h = _norm_apply(cfg, bp["ln2"], x)
+            x = _residual(cfg, x, mlp_apply(bp["mlp"], h))
         return x, new_cache, aux
 
     def _run_stack(self, params, x, *, mode, positions=None, pos=None,
@@ -295,7 +320,7 @@ class TransformerLM:
         x, aux = self.forward_hidden(params, tokens,
                                      vision_embeds=vision_embeds,
                                      encoder_frames=encoder_frames)
-        return logits_from(params["embed"], x), aux
+        return _logits(self.cfg, params, x), aux
 
     def forward_hidden(self, params: Dict, tokens: jnp.ndarray, *,
                        vision_embeds: Optional[jnp.ndarray] = None,
@@ -305,8 +330,8 @@ class TransformerLM:
         the chunked-loss path (§Perf) fuses logits into the loss instead.
         Returns (hidden (B, S, D), aux)."""
         cfg = self.cfg
-        x = embed_tokens(params["embed"], tokens,
-                         jnp.arange(tokens.shape[1]) if cfg.learned_pos else None)
+        x = _embed(cfg, params, tokens,
+                   jnp.arange(tokens.shape[1]) if cfg.learned_pos else None)
         if vision_embeds is not None:
             x = jnp.concatenate([vision_embeds.astype(x.dtype), x], axis=1)
         S = x.shape[1]
@@ -325,8 +350,8 @@ class TransformerLM:
         """Prefill: runs the full prompt, fills the cache, returns
         (last-token logits, cache)."""
         cfg = self.cfg
-        x = embed_tokens(params["embed"], tokens,
-                         jnp.arange(tokens.shape[1]) if cfg.learned_pos else None)
+        x = _embed(cfg, params, tokens,
+                   jnp.arange(tokens.shape[1]) if cfg.learned_pos else None)
         if vision_embeds is not None:
             x = jnp.concatenate([vision_embeds.astype(x.dtype), x], axis=1)
         S = x.shape[1]
@@ -338,7 +363,7 @@ class TransformerLM:
                                             positions=positions, cache=cache,
                                             memory=memory)
         x = _norm_apply(cfg, params["final_norm"], x[:, -1:])
-        return logits_from(params["embed"], x), new_cache
+        return _logits(cfg, params, x), new_cache
 
     def prefill_ragged(self, params: Dict, tokens: jnp.ndarray,
                        lengths: jnp.ndarray, cache: Dict,
@@ -351,11 +376,13 @@ class TransformerLM:
         a lone batch-1 prefill of its prompt would have written.  Returns
         (per-slot next-token logits (B, 1, V), cache).
 
-        Restricted to attention-only dense stacks: a recurrent state (RG-LRU
-        h, SSD h, conv taps) would absorb the pad tail, and MoE
-        capacity-factor routing couples slots through the shared token
-        budget — those architectures prefill per-request instead (the serve
-        engine handles the fallback).
+        Restricted to dense stacks of attention and SSD blocks.  An SSD
+        block leaves its state exactly where the row's last real token left
+        it (Δ = 0 at pad positions, conv taps from the last real ones; see
+        ``models/layers/ssd.py``).  An RG-LRU state would absorb the pad
+        tail, and MoE capacity-factor routing couples slots through the
+        shared token budget: those architectures prefill per-request
+        instead (the serve engine handles the fallback).
 
         ``start_pos`` (B,) turns this into a **tail** prefill: slot b's
         tokens are the uncached suffix of its prompt, occupying absolute
@@ -363,13 +390,18 @@ class TransformerLM:
         cache arrives with the prefix K/V already restored
         (``serve/prefix_cache.py``).  Rows with ``start_pos[b] == 0``
         degrade to a plain full prefill, so one compiled program serves
-        waves mixing cache hits and misses.
+        waves mixing cache hits and misses.  It needs an attention-only
+        stack: an SSD state cannot be restored from a prefix.
         """
         cfg = self.cfg
-        if any(k not in ATTENTION_KINDS for k in cfg.pattern):
-            raise ValueError(f"{cfg.name}: prefill_ragged requires an "
-                             "attention-only pattern (recurrent state would "
+        if any(k not in RAGGED_KINDS for k in cfg.pattern):
+            raise ValueError(f"{cfg.name}: prefill_ragged requires attention "
+                             "and SSD blocks only (an RG-LRU state would "
                              "absorb the pad tail)")
+        if start_pos is not None and any(k not in ATTENTION_KINDS
+                                         for k in cfg.pattern):
+            raise ValueError(f"{cfg.name}: a tail prefill (start_pos=) "
+                             "needs an attention-only pattern")
         if cfg.num_experts or cfg.cross_attention or cfg.vision_tokens:
             raise ValueError(f"{cfg.name}: prefill_ragged supports dense "
                              "text-only decoders")
@@ -381,7 +413,7 @@ class TransformerLM:
             start_pos = jnp.asarray(start_pos, jnp.int32)
             emb_pos = (start_pos[:, None] + jnp.arange(S)[None, :]
                        if cfg.learned_pos else None)
-        x = embed_tokens(params["embed"], tokens, emb_pos)
+        x = _embed(cfg, params, tokens, emb_pos)
         positions = jnp.arange(S)
         x, aux, new_cache = self._run_stack(params, x, mode="prefill",
                                             positions=positions, cache=cache,
@@ -392,7 +424,7 @@ class TransformerLM:
         x_last = jnp.take_along_axis(
             x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)
         x_last = _norm_apply(cfg, params["final_norm"], x_last)
-        return logits_from(params["embed"], x_last), new_cache
+        return _logits(cfg, params, x_last), new_cache
 
     def decode_step(self, params: Dict, token: jnp.ndarray, pos: jnp.ndarray,
                     cache: Dict):
@@ -405,8 +437,8 @@ class TransformerLM:
             emb_pos = pos[:, None] if pos.ndim == 1 else pos[None]
         else:
             emb_pos = None
-        x = embed_tokens(params["embed"], token, emb_pos)
+        x = _embed(cfg, params, token, emb_pos)
         x, aux, new_cache = self._run_stack(params, x, mode="decode", pos=pos,
                                             cache=cache)
         x = _norm_apply(cfg, params["final_norm"], x)
-        return logits_from(params["embed"], x), new_cache
+        return _logits(cfg, params, x), new_cache

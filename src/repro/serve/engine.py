@@ -7,16 +7,33 @@ work is three jitted functions:
 
   * **ragged prefill** — newly admitted prompts of *mixed* lengths are
     right-padded and prefilled together (``TransformerLM.prefill_ragged``);
-    pad columns never enter the shared cache, so each slot's cache is
-    exactly what a lone batch-1 prefill would have written.  Architectures
-    whose state a pad tail would corrupt (recurrent blocks, MoE capacity
-    routing, encoder/vision frontends) prefill per-request into a batch-1
-    cache that is scattered into the slot instead.
+    pad columns never enter the shared cache, and SSD blocks hold their
+    state through a pad tail, so each slot's cache (K/V of attention
+    blocks, ``h`` and conv taps of SSD blocks, side by side) is what a lone
+    batch-1 prefill would have written.  Architectures whose state a pad
+    tail would corrupt (RG-LRU blocks, MoE capacity routing, encoder/vision
+    frontends) prefill per-request into a batch-1 cache that is scattered
+    into the slot instead.  A wave's sub-cache starts from zeros and the
+    scatter overwrites each admitted slot's whole row, so a reused slot
+    carries nothing of its last request.
   * **fused decode** — ONE masked decode step advances every busy slot
     regardless of where each sits in its sequence: ``pos`` is a (B,)
     vector and the attention mask is per-slot (``models/layers/attention``).
   * **cache scatter** — drops a prefilled request into its slot of the
     shared cache.
+
+The scatter donates the shared cache, and the ragged prefill makes its
+zeroed sub-cache inside the program, so admission never holds a second
+copy of the shared cache or a zeroed sub-cache beside the prefilled one.
+
+Host spans (``repro.core.trace``): ``serve.admit`` around a turn's
+admission (wave inputs, prefill, scatter, first tokens), inside it
+``serve.prefill`` around each wave's prefill and scatter dispatch and the
+read-back of its first tokens, and ``serve.decode`` around the decode
+step's dispatch and the read-back of its tokens.  Counters:
+``serve.admit_waves`` (prefill programs run), ``serve.prefill_tokens``
+(positions they computed, pad included) and ``serve.prefill_pad_tokens``
+(of those, pad columns and dummy rows).
 
 Slot admission, retirement, and backfill are host-side and owned by
 :class:`repro.serve.scheduler.SlotScheduler`; the engine is the device
@@ -41,8 +58,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import trace
 from repro.models.config import ATTENTION_KINDS, ArchConfig
-from repro.models.transformer import TransformerLM
+from repro.models.transformer import RAGGED_KINDS, TransformerLM
 from repro.serve.scheduler import Request, SlotScheduler
 
 __all__ = ["Request", "ServeEngine"]
@@ -50,6 +68,14 @@ __all__ = ["Request", "ServeEngine"]
 
 def _now_zero() -> float:
     return 0.0
+
+
+def _count_wave(positions: int, real: int) -> None:
+    """Count one prefill program: ``positions`` computed, ``real`` of them
+    prompt tokens of admitted requests."""
+    trace.count("serve.admit_waves")
+    trace.count("serve.prefill_tokens", positions)
+    trace.count("serve.prefill_pad_tokens", positions - real)
 
 
 def serve_scatter(cache, sub_cache, slots: jnp.ndarray):
@@ -75,11 +101,14 @@ class ServeEngine:
 
     def __init__(self, cfg: ArchConfig, params, batch_size: int, max_seq: int,
                  greedy: bool = True, mesh=None, rules=None, param_axes=None,
-                 prefix_cache=None):
+                 prefix_cache=None, pad_to: int = 8):
         self.cfg = cfg
         self.model = TransformerLM(cfg)
         self.batch = int(batch_size)
         self.max_seq = int(max_seq)
+        # admission waves pad their sequence to a multiple of this: one
+        # compiled prefill per bucket (and wave size) that traffic reaches
+        self.pad_to = int(pad_to)
         self.greedy = greedy
         self.mesh = mesh
         if mesh is not None:
@@ -96,19 +125,23 @@ class ServeEngine:
             from repro.models.layers.quant import quantize_model_params
             params = quantize_model_params(params, cfg.quantize)
         self.params = params
-        # ragged (batched mixed-length) prefill is exact only when no
-        # cross-slot or sequential state exists; everything else prefills
-        # per-request and scatters into its slot
+        # ragged (batched mixed-length) prefill is exact only where no
+        # cross-slot state exists and every block's state survives a pad
+        # tail; everything else prefills per-request and scatters into its
+        # slot
         self.ragged_ok = (
-            all(k in ATTENTION_KINDS for k in cfg.pattern)
+            all(k in RAGGED_KINDS for k in cfg.pattern)
             and not cfg.num_experts and not cfg.cross_attention
             and not cfg.vision_tokens)
         # each program is named for the trace: jit_serve_prefill and so on
         def serve_prefill(p, t, c):
             return self.model.prefill(p, t, c)
 
-        def serve_prefill_ragged(p, t, n, c):
-            return self.model.prefill_ragged(p, t, n, c)
+        def serve_prefill_ragged(p, t, n):
+            # the wave's sub-cache starts from zeros made inside the program
+            # (no second cache-sized argument); a reused slot carries nothing
+            return self.model.prefill_ragged(
+                p, t, n, self.model.init_cache(t.shape[0], self.max_seq))
 
         def serve_decode(p, t, pos, c):
             return self.model.decode_step(p, t, pos, c)
@@ -116,15 +149,17 @@ class ServeEngine:
         self._prefill = jax.jit(serve_prefill)
         self._prefill_ragged = jax.jit(serve_prefill_ragged)
         self._decode = jax.jit(serve_decode)
-        self._scatter = jax.jit(serve_scatter)
+        self._scatter = jax.jit(serve_scatter, donate_argnums=0)
         # radix prefix KV cache (serve/prefix_cache.py): admission becomes
         # match → restore cached blocks → prefill the uncached tail only
         self.prefix_cache = prefix_cache
         if prefix_cache is not None:
-            if not self.ragged_ok:
+            if not (self.ragged_ok
+                    and all(k in ATTENTION_KINDS for k in cfg.pattern)):
                 raise ValueError(
-                    "prefix_cache requires the ragged-prefill path "
-                    "(attention-only dense decoder)")
+                    "prefix_cache requires the ragged-prefill path of an "
+                    "attention-only dense decoder (no recurrent state "
+                    "snapshots)")
             if cfg.cache_dtype == "int8":
                 raise ValueError(
                     "prefix_cache needs exact KV restore, but cache_dtype="
@@ -158,7 +193,7 @@ class ServeEngine:
     # admission → prefill
     # ------------------------------------------------------------------ #
     def _prefill_into(self, cache, admits: List[Tuple[int, Request]],
-                      pad_to: int = 8,
+                      pad_to: Optional[int] = None,
                       wave_pad: Optional[int] = None) -> Tuple[Any, np.ndarray]:
         """Prefill the admitted requests and scatter them into their slots.
         Returns (cache, first greedy token per admit).
@@ -178,6 +213,7 @@ class ServeEngine:
         ragged path excludes prefill per-request (one compile per distinct
         prompt length) and scatter batch-1 caches.
         """
+        pad_to = pad_to or self.pad_to
         slots = np.asarray([s for s, _ in admits], np.int32)
         reqs = [r for _, r in admits]
         lens = np.asarray([len(r.prompt) for r in reqs], np.int32)
@@ -201,19 +237,25 @@ class ServeEngine:
                 padded[i, : lens[i]] = r.prompt
                 full_lens[i] = lens[i]
                 full_slots[i] = slots[i]
-            sub = self.model.init_cache(wb, self.max_seq)
-            logits, sub = self._prefill_ragged(
-                self.params, jnp.asarray(padded), jnp.asarray(full_lens), sub)
-            cache = self._scatter(cache, sub, jnp.asarray(full_slots))
-            first = np.asarray(jnp.argmax(logits[:, -1], axis=-1), np.int32)
+            _count_wave(wb * S, int(lens.sum()))
+            with trace.span("serve.prefill"):
+                logits, sub = self._prefill_ragged(
+                    self.params, jnp.asarray(padded), jnp.asarray(full_lens))
+                cache = self._scatter(cache, sub, jnp.asarray(full_slots))
+                first = np.asarray(jnp.argmax(logits[:, -1], axis=-1),
+                                   np.int32)
             return cache, first[:n]
         first = np.zeros(len(reqs), np.int32)
         for i, r in enumerate(reqs):
             sub = self.model.init_cache(1, self.max_seq)
-            logits, sub = self._prefill(
-                self.params, jnp.asarray(r.prompt, jnp.int32)[None, :], sub)
-            cache = self._scatter(cache, sub, jnp.asarray(slots[i : i + 1]))
-            first[i] = int(jnp.argmax(logits[0, -1]))
+            _count_wave(len(r.prompt), len(r.prompt))
+            with trace.span("serve.prefill"):
+                logits, sub = self._prefill(
+                    self.params, jnp.asarray(r.prompt, jnp.int32)[None, :],
+                    sub)
+                cache = self._scatter(cache, sub,
+                                      jnp.asarray(slots[i : i + 1]))
+                first[i] = int(jnp.argmax(logits[0, -1]))
         return cache, first
 
     def _prefill_into_cached(self, cache, reqs: List[Request],
@@ -260,9 +302,9 @@ class ServeEngine:
                 padded[j, : lens[i]] = reqs[i].prompt
                 glens[j] = lens[i]
                 gslots[j] = slots[i]
-            sub = self.model.init_cache(wbg, self.max_seq)
+            _count_wave(wbg * S, int(lens[idx].sum()))
             logits, sub = self._prefill_ragged(
-                self.params, jnp.asarray(padded), jnp.asarray(glens), sub)
+                self.params, jnp.asarray(padded), jnp.asarray(glens))
             cache = self._scatter(cache, sub, jnp.asarray(gslots))
             first[idx] = np.asarray(jnp.argmax(logits[:, -1], axis=-1),
                                     np.int32)[:k]
@@ -288,6 +330,7 @@ class ServeEngine:
                         for j, i in enumerate(idx)
                         for d, node in enumerate(matches[i].nodes)]
             sub = pc.restore_into(sub, restores)
+            _count_wave(wbg * S, int(tails.sum()))
             logits, sub = self._prefill_ragged_start(
                 self.params, jnp.asarray(padded), jnp.asarray(glens),
                 jnp.asarray(gstarts), sub)
@@ -346,10 +389,11 @@ class ServeEngine:
                 time.sleep(min(max(nxt - now(), 0.0), 0.001))
                 continue
             if admits:
-                cache, first = self._prefill_into(cache, admits)
-                for (slot, req), tok in zip(admits, first):
-                    toks[slot] = tok
-                    pos[slot] = len(req.prompt)
+                with trace.span("serve.admit"):
+                    cache, first = self._prefill_into(cache, admits)
+                    for (slot, req), tok in zip(admits, first):
+                        toks[slot] = tok
+                        pos[slot] = len(req.prompt)
             # emit one token per busy slot; retire EOS / exhausted slots
             t = now()
             for slot in range(B):
@@ -366,11 +410,12 @@ class ServeEngine:
             if sched.busy == 0:
                 continue  # all retired; backfill (or finish) next iteration
             # ONE fused masked step advances every slot, each at its own pos
-            logits, cache = self._decode(
-                self.params, jnp.asarray(toks[:, None], jnp.int32),
-                jnp.asarray(pos, jnp.int32), cache)
-            step_toks = np.asarray(jnp.argmax(logits[:, -1], axis=-1),
-                                   np.int32)
+            with trace.span("serve.decode"):
+                logits, cache = self._decode(
+                    self.params, jnp.asarray(toks[:, None], jnp.int32),
+                    jnp.asarray(pos, jnp.int32), cache)
+                step_toks = np.asarray(jnp.argmax(logits[:, -1], axis=-1),
+                                       np.int32)
             for slot in range(B):
                 if sched.slots[slot] is not None:
                     toks[slot] = step_toks[slot]
@@ -452,7 +497,8 @@ class ServeEngine:
     # ------------------------------------------------------------------ #
     # warmup (perf reporting excludes compile time)
     # ------------------------------------------------------------------ #
-    def warmup(self, prompt_lens: Sequence[int] = (), pad_to: int = 8) -> None:
+    def warmup(self, prompt_lens: Sequence[int] = (),
+               pad_to: Optional[int] = None) -> None:
         """Compile the fused decode step, the cache scatter, and every
         prefill shape the given prompt lengths will hit, so serving (and
         the launcher's perf report) never pays compile time mid-stream.
@@ -474,6 +520,7 @@ class ServeEngine:
         blocks are dropped from the trie afterwards (``reset``) so warmup
         never pollutes live hit-rate stats.
         """
+        pad_to = pad_to or self.pad_to
         lens = sorted(set(int(n) for n in prompt_lens))
         cache = self.init_shared_cache()
         if lens and self.ragged_ok:

@@ -108,6 +108,8 @@ def make_train_step(cfg: ArchConfig, optimizer: Optional[OptimizerDef] = None,
             if cfg.vision_tokens:
                 hidden = hidden[:, cfg.vision_tokens:]
             B, S, D = hidden.shape
+            if cfg.logits_scaling != 1.0:    # logits / s == (hidden / s) · table
+                hidden = hidden / cfg.logits_scaling
             table = params["embed"]["head"].T if "head" in params["embed"] \
                 else params["embed"]["tok"]
             loss = chunked_cross_entropy_from_hidden(

@@ -170,10 +170,11 @@ def test_sliding_window_arch_parity(gemma_engine):
 
 
 def test_recurrent_arch_per_request_fallback_parity():
-    """RG-LRU/SSD state would absorb a pad tail, so those stacks prefill
+    """RG-LRU state would absorb a pad tail, so such stacks prefill
     per-request into the shared cache — fused per-slot decode still runs
-    and must match slot-at-a-time."""
-    cfg = get_smoke("mamba2-2.7b")
+    and must match slot-at-a-time.  (SSD stacks take the ragged path: their
+    state holds through a pad tail; ``tests/test_granite_hybrid.py``.)"""
+    cfg = get_smoke("recurrentgemma-9b")
     params, _ = init_model(KEY, cfg)
     engine = ServeEngine(cfg, params, batch_size=2, max_seq=64)
     assert not engine.ragged_ok
@@ -184,12 +185,12 @@ def test_recurrent_arch_per_request_fallback_parity():
 
 
 def test_prefill_ragged_rejects_recurrent_stacks():
-    cfg = get_smoke("mamba2-2.7b")
+    cfg = get_smoke("recurrentgemma-9b")
     params, _ = init_model(KEY, cfg)
     model = TransformerLM(cfg)
     cache = model.init_cache(2, 32)
     toks = jnp.zeros((2, 8), jnp.int32)
-    with pytest.raises(ValueError, match="attention-only"):
+    with pytest.raises(ValueError, match="RG-LRU"):
         model.prefill_ragged(params, toks, jnp.asarray([4, 8]), cache)
 
 
