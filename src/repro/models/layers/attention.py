@@ -10,9 +10,11 @@ block sees only the key span its mask admits:
     sliding W     key span = C + W_pad                  O(S·W)
     chunked C_a   key span = its own attention chunk    O(S·C_a)
 
-GQA is computed by broadcasting kv heads to q heads (``jnp.repeat``) so the
-head dimension shards cleanly on the mesh "model" axis; XLA fuses the
-broadcast into the einsum.
+GQA contracts the queries in their kv-head groups: q is viewed as
+(B, S, KV, H // KV, hd) and multiplied against K/V at their own KV heads, so
+each K/V head (and each cache entry) is read once for its group of query
+heads and never copied to query-head width.  Query head h pairs with kv head
+h // (H // KV), the pairing ``jnp.repeat(k, H // KV, axis=2)`` would give.
 """
 from __future__ import annotations
 
@@ -122,19 +124,20 @@ def _cache_read(cache: Dict[str, jnp.ndarray]) -> Tuple[jnp.ndarray, jnp.ndarray
 # --------------------------------------------------------------------------- #
 def _sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
           mask: jnp.ndarray, scale: float) -> jnp.ndarray:
-    """q: (B, Sq, H, hd)  k/v: (B, Sk, H, hd)  mask: (B|1, 1, Sq, Sk) bool.
-    fp32 softmax, bf16 matmuls with fp32 accumulation."""
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+    """q: (B, Sq, H, hd)  k/v: (B, Sk, KV, hd)  mask: (B|1, 1, Sq, Sk) bool.
+    The H query heads are contracted in KV groups of G = H // KV against
+    their kv head (MHA is G = 1).  fp32 softmax, bf16 matmuls with fp32
+    accumulation."""
+    B, Sq, H, hd = q.shape
+    kv = k.shape[2]
+    qg = q.reshape(B, Sq, kv, H // kv, hd)
+    logits = jnp.einsum("bqngd,bknd->bngqk", qg, k,
                         preferred_element_type=jnp.float32) * scale
-    logits = jnp.where(mask, logits, NEG_INF)
+    logits = jnp.where(mask[:, :, None], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v,
+    out = jnp.einsum("bngqk,bknd->bqngd", probs.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-    return out.astype(q.dtype)
-
-
-def _repeat_kv(k: jnp.ndarray, groups: int) -> jnp.ndarray:
-    return jnp.repeat(k, groups, axis=2) if groups > 1 else k
+    return out.reshape(B, Sq, H, hd).astype(q.dtype)
 
 
 def _round_up(x: int, m: int) -> int:
@@ -146,7 +149,7 @@ def _round_up(x: int, m: int) -> int:
 # --------------------------------------------------------------------------- #
 def _prefill_attend(q, k, v, kind: BlockKind, cfg: ArchConfig,
                     positions: jnp.ndarray) -> jnp.ndarray:
-    """q/k/v: (B, S, H, hd) with kv already broadcast to H heads.
+    """q: (B, S, H, hd), k/v: (B, S, KV, hd) at their own kv heads.
     positions: (S,) int32 absolute positions (shared across batch)."""
     B, S, H, hd = q.shape
     scale = cfg.attention_scale
@@ -224,8 +227,6 @@ def attn_apply(
 ) -> Tuple[jnp.ndarray, Optional[Dict[str, jnp.ndarray]]]:
     """Returns (output (B,S,D), updated_cache_or_None)."""
     B, S, D = x.shape
-    h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
-    groups = h // kv
     rope_on = use_rope and kind != BlockKind.ATTN_NOPE
 
     q = linear_or_quant(x, params["wq"], "bsd,dhk->bshk")
@@ -251,17 +252,14 @@ def attn_apply(
             pos_q = positions if positions is not None else jnp.arange(S)
             if rope_on:
                 q = apply_rope(q, pos_q[None, :], cfg.rope_base)
-            kf = _repeat_kv(k, groups)
-            vf = _repeat_kv(v, groups)
             mask = jnp.ones((1, 1, S, k.shape[1]), bool)
-            out = _sdpa(q, kf, vf, mask, cfg.attention_scale)
+            out = _sdpa(q, k, v, mask, cfg.attention_scale)
             cross_cache = {"k": k, "v": v} if cache is not None else None
             return _out_proj(out, params), cross_cache
         if start_pos is not None:
             return _prefill_offset(params, q, k, v, cfg=cfg, kind=kind,
                                    cache=cache, lengths=lengths,
-                                   start_pos=start_pos, groups=groups,
-                                   rope_on=rope_on)
+                                   start_pos=start_pos, rope_on=rope_on)
         pos_q = positions if positions is not None else jnp.arange(S)
         if rope_on:
             q = apply_rope(q, pos_q[None, :], cfg.rope_base)
@@ -270,8 +268,7 @@ def attn_apply(
             # write the (possibly windowed) tail of K/V into the cache for
             # subsequent decode
             cache = _prefill_fill_cache(cache, k, v, lengths)
-        out = _prefill_attend(q, _repeat_kv(k, groups), _repeat_kv(v, groups),
-                              kind, cfg, pos_q)
+        out = _prefill_attend(q, k, v, kind, cfg, pos_q)
         return _out_proj(out, params), cache
 
     # ---------------- decode: S == 1, attend to cache ---------------- #
@@ -309,13 +306,12 @@ def attn_apply(
         kc, vc = _cache_read(cache)
         mask = jnp.ones((1, 1, 1, kc.shape[1]), bool)
         new_cache = cache
-    out = _sdpa(q, _repeat_kv(kc, groups), _repeat_kv(vc, groups), mask,
-                cfg.attention_scale)
+    out = _sdpa(q, kc, vc, mask, cfg.attention_scale)
     return _out_proj(out, params), new_cache
 
 
 def _prefill_offset(params, q, k, v, *, cfg: ArchConfig, kind: BlockKind,
-                    cache, lengths, start_pos, groups: int, rope_on: bool):
+                    cache, lengths, start_pos, rope_on: bool):
     """Offset ragged prefill: slot b's tokens are its prompt *tail*,
     occupying absolute positions ``start_pos[b] .. start_pos[b]+lengths[b]-1``
     on top of a cache whose ring already holds the prefix K/V (restored by
@@ -362,8 +358,7 @@ def _prefill_offset(params, q, k, v, *, cfg: ArchConfig, kind: BlockKind,
     mask = jnp.concatenate([mp, mt], axis=-1)[:, None]         # (B,1,S,r+S)
     k_all = jnp.concatenate([kc.astype(k.dtype), k], axis=1)
     v_all = jnp.concatenate([vc.astype(v.dtype), v], axis=1)
-    out = _sdpa(q, _repeat_kv(k_all, groups), _repeat_kv(v_all, groups),
-                mask, cfg.attention_scale)
+    out = _sdpa(q, k_all, v_all, mask, cfg.attention_scale)
     cache = _prefill_fill_cache(cache, k, v, lengths, start=start)
     return _out_proj(out, params), cache
 

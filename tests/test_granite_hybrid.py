@@ -221,37 +221,38 @@ def test_ssd_pad_tail_leaves_state_as_the_unpadded_row(length):
 
 # forward logits, prefill logits and one decode step of every other smoke
 # config, on fixed tokens, as the code before the granite fields computed
-# them: sums of all logits, of the last position's first 8, of the prefill
-# logits, of the decode logits and of row 1's first 8.  mamba2 is not here:
-# its conv now runs over xBC, as published.
+# them with GQA contracted in kv-head groups: sums of all logits, of the
+# last position's first 8, of the prefill logits, of the decode logits and
+# of row 1's first 8.  mamba2 is not here: its conv now runs over xBC, as
+# published.
 BEFORE = {
-    "llama4-scout-17b-16e": (424.2310375611487, 3.3938870429992676,
-                             18.03604451645515, -1.8165055026765913,
-                             1.2160869240760803),
-    "mixtral-8x22b": (170.16507927293424, 8.31778085231781,
-                      -53.9530827839626, 23.660967726958916,
-                      2.651760458946228),
+    "llama4-scout-17b-16e": (424.23037925111566, 3.3938805758953094,
+                             18.036012916680193, -1.8165518603054807,
+                             1.2160989046096802),
+    "mixtral-8x22b": (170.16474323920556, 8.317782938480377,
+                      -53.95307354955003, 23.660836843191646,
+                      2.6517725586891174),
     "whisper-small": (1200.080329366534, 3.306814521551132,
                       66.57426917299745, 49.87222117872443,
                       3.938631370663643),
-    "granite-3-8b": (-268.88182557182154, 2.5727102905511856,
-                     120.43120515299961, 137.02978934394196,
-                     5.516929924488068),
-    "llava-next-34b": (-87.20386825258902, -0.8827010989189148,
-                       139.3338599820272, 125.2710429924773,
-                       5.6609238386154175),
+    "granite-3-8b": (-268.8813643330359, 2.572710707783699,
+                     120.4312344477512, 137.02978613879532,
+                     5.516932487487793),
+    "llava-next-34b": (-87.2040806554869, -0.8827031254768372,
+                       139.33390398003394, 125.27101026917808,
+                       5.660919964313507),
     "qwen1.5-32b": (603.5560335336922, -6.159585773944855,
                     20.22703503369121, 52.292271421523765,
                     -3.503520578145981),
     "recurrentgemma-9b": (-200.25025446222935, -0.6831352552399039,
                           37.160228891065344, 62.0592256935779,
                           0.403882160782814),
-    "gemma3-1b": (-620.9374054260552, -3.5384793430566788,
-                  -96.03260159422643, -101.02133663685527,
-                  -2.4666807651519775),
-    "qwen2-1.5b": (-268.88182557182154, 2.5727102905511856,
-                   120.43120515299961, 137.02978934394196,
-                   5.516929924488068),
+    "gemma3-1b": (-620.9372202632367, -3.5384716540575027,
+                  -96.03266918403096, -101.02128124109004,
+                  -2.4666707515716553),
+    "qwen2-1.5b": (-268.8813643330359, 2.572710707783699,
+                   120.4312344477512, 137.02978613879532,
+                   5.516932487487793),
 }
 
 

@@ -231,31 +231,72 @@ def test_pallas_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _serving_args(cfg, slots, max_seq, one_chip):
+    """(model, parameter shapes, cache shapes) of ``cfg`` on one chip."""
+    from repro.models.transformer import TransformerLM, init_model
+
+    model = TransformerLM(cfg)
+    on_chip = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: _sds(a.shape, a.dtype, one_chip), tree)
+    params = on_chip(jax.eval_shape(lambda k: init_model(k, cfg)[0],
+                                    jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(lambda: model.init_cache(slots, max_seq)))
+    return model, params, cache
+
+
+def _compiled_decode(cfg, slots, max_seq, one_chip):
+    model, params, cache = _serving_args(cfg, slots, max_seq, one_chip)
+    fn = jax.jit(lambda p, t, pos, c: model.decode_step(p, t, pos, c))
+    return fn.lower(params, _sds((slots, 1), np.int32, one_chip),
+                    _sds((slots,), np.int32, one_chip), cache).compile()
+
+
 @pytest.mark.parametrize("program", ["prefill_ragged", "decode_step"])
 def test_qwen2_serving_program_fits_one_chip(one_chip, program):
     """qwen2-1.5b at its published widths, as ``ServeEngine`` jits it for 4
     slots and a 2,048-token cache: a 1,024-token ragged prefill wave and the
     fused decode step compile and fit one chip's HBM."""
     from repro.configs import get_config
-    from repro.models.transformer import TransformerLM, init_model
 
     cfg = get_config("qwen2-1.5b")
-    model = TransformerLM(cfg)
     slots, max_seq = 4, 2_048
-    on_chip = lambda tree: jax.tree.map(  # noqa: E731
-        lambda a: _sds(a.shape, a.dtype, one_chip), tree)
-    params = on_chip(jax.eval_shape(lambda k: init_model(k, cfg)[0],
-                                    jax.random.PRNGKey(0)))
-    cache = on_chip(jax.eval_shape(lambda: model.init_cache(slots, max_seq)))
-    lens = _sds((slots,), np.int32, one_chip)
     if program == "prefill_ragged":
+        model, params, cache = _serving_args(cfg, slots, max_seq, one_chip)
+        lens = _sds((slots,), np.int32, one_chip)
         fn = jax.jit(lambda p, t, n, c: model.prefill_ragged(p, t, n, c))
-        args = (params, _sds((slots, 1_024), np.int32, one_chip), lens, cache)
+        compiled = fn.lower(params, _sds((slots, 1_024), np.int32, one_chip),
+                            lens, cache).compile()
     else:
-        fn = jax.jit(lambda p, t, pos, c: model.decode_step(p, t, pos, c))
-        args = (params, _sds((slots, 1), np.int32, one_chip), lens, cache)
-    compiled = fn.lower(*args).compile()
+        compiled = _compiled_decode(cfg, slots, max_seq, one_chip)
     assert _footprint(compiled) < V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("arch", ["qwen2-1.5b", "granite-4.0-h-micro"])
+def test_decode_step_reads_kv_at_kv_head_width(one_chip, arch):
+    """The decode step of the serving cells (32 slots x 1,024 positions)
+    contracts the queries against K/V at their own kv heads: no op of the
+    compiled program outputs a cache-sized tensor at query-head width
+    (slots x positions x query heads x head size, in any order, unit axes
+    aside), as K/V repeated to every query head would; and it fits one
+    chip."""
+    from repro.configs import get_config
+    from repro.configs.granite_4_0_h_micro import FULL as GRANITE
+
+    cfg = GRANITE if arch == "granite-4.0-h-micro" else get_config(arch)
+    slots, max_seq = 32, 1_024
+    compiled = _compiled_decode(cfg, slots, max_seq, one_chip)
+    assert _footprint(compiled) < V5E_HBM_BYTES
+
+    wide = sorted([slots, max_seq, cfg.num_heads, cfg.resolved_head_dim])
+    at_kv = sorted([slots, max_seq, cfg.num_kv_heads, cfg.resolved_head_dim])
+    shapes = []
+    for line in compiled.as_text().splitlines():
+        m = _HLO_OP.search(line)
+        if m is not None:
+            dims = m.group(1).split("[", 1)[1].rstrip("]").split(",")
+            shapes.append(sorted(int(d) for d in dims if d and d != "1"))
+    assert at_kv in shapes           # the cache itself is read per layer
+    assert wide not in shapes
 
 
 @pytest.mark.parametrize("name", ["logreg_grad", "kmeans_assign",
