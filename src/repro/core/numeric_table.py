@@ -207,7 +207,7 @@ class MLNumericTable:
         """Run ``block_fn`` on every partition; return stacked results
         (num_shards, ...).  Execution is delegated to the shared
         :class:`repro.core.runner.DistributedRunner` engine (shard_map when
-        a mesh is attached, a partition loop otherwise)."""
+        a mesh is attached, one partition after another otherwise)."""
         from repro.core.runner import DistributedRunner
 
         runner = DistributedRunner.for_table(self)

@@ -2,19 +2,23 @@
 
 The paper's claim (§III, §IV) is that one uniform contract —
 ``Algorithm.train(data, params) -> Model`` over a row-partitioned table —
-expresses many distributed ML algorithms.  The seed code had the contract
-but each algorithm wired its own ``shard_map`` loop.  This module is the
-single place that owns distributed execution:
+expresses many distributed ML algorithms.  This module is the single place
+that owns distributed execution:
 
   * **mesh + partition layout** — delegated to :mod:`repro.core.partition`,
     shared with :class:`repro.core.numeric_table.MLNumericTable` so table
     placement and execution can never disagree;
-  * **per-round combine** — :mod:`repro.core.collectives` with
-    :class:`CollectiveSchedule` as a pluggable parameter, so the paper's
-    §IV-A schedule comparison is a knob every algorithm exposes;
-  * **iteration** — one jitted ``lax.scan`` over rounds with the carry
-    donated on accelerators, so per-round parameter buffers are reused
-    instead of reallocated.
+  * **one partition pass** — ``_pass`` runs a local function on every
+    partition and combines the results; it is the only code that tells a
+    mesh (``shard_map`` plus a collective from :mod:`repro.core.collectives`,
+    with :class:`CollectiveSchedule` as a knob every algorithm exposes) from
+    emulated partitions (a ``lax.map`` over the blocks of one device, one
+    after another, then the algebraically-equal local reduction);
+  * **one loop program** — ``_loop`` is a ``lax.scan`` over round ids, each
+    round a pass, a combine and an ``update``.  The resident rounds of
+    :meth:`DistributedRunner.run_rounds` are its one-chunk case and the
+    streaming epochs of :meth:`DistributedRunner.run_epochs` its chunked
+    case; both are jitted with the carry donated on accelerators.
 
 Algorithms express their per-partition compute as *pure local functions*
 ``f(block, state, round) -> partial`` (or ``f(block, *broadcast) ->
@@ -24,24 +28,17 @@ partial`` for one-shot passes) and delegate everything else here:
     final = runner.run_rounds(table, init, local_step, num_rounds,
                               combine="mean")
 
-Both execution modes of the table are supported transparently: **mesh mode**
-(shard_map over the data axes; collectives lower to real HLO) and
-**emulated mode** (logical partitions on one device, run one after
-another with the program one chip of the mesh runs; the combine is the
-algebraically-equal local reduction).  See ``docs/architecture.md`` for the
-data-flow diagram and ``docs/api.md`` for the full surface.
+See ``docs/architecture.md`` for the data-flow diagram and ``docs/api.md``
+for the full surface.
 
-Beyond the paper's resident-table loop, the runner has a **streaming
-mode** (:meth:`DistributedRunner.run_epochs`): each epoch consumes one
-host window from a :class:`repro.data.pipeline.BatchIterator` (placed on
-the mesh by ``shard_batch``) and runs a chunked, jitted ``lax.scan`` of
-minibatch rounds over the device-resident window — so training is not
-bounded by device memory.  Paired with :class:`CheckpointPolicy` (periodic
-snapshots of state + epoch + stream position + rng through
-:mod:`repro.checkpoint.store`) and :meth:`DistributedRunner.resume`, a
-run killed mid-flight restarts bit-for-bit on the same mesh — the
-checkpoint-and-restart fault-tolerance story that replaces the paper's
-Spark lineage.
+In **streaming mode** (:meth:`DistributedRunner.run_epochs`) each epoch
+consumes one host window from a :class:`repro.data.pipeline.BatchIterator`
+(placed on the mesh by ``shard_batch``) and runs the loop program over the
+window's minibatch chunks — so training is not bounded by device memory.
+Paired with :class:`CheckpointPolicy` (periodic snapshots of state + epoch +
+stream position + rng through :mod:`repro.checkpoint.store`) and
+:meth:`DistributedRunner.resume`, a run killed mid-flight restarts
+bit-for-bit on the same mesh.
 """
 from __future__ import annotations
 
@@ -60,7 +57,6 @@ from repro.core import trace
 from repro.core.compat import shard_map
 from repro.core.collectives import (
     CollectiveSchedule,
-    SyncPolicy,
     combine_concat,
     combine_mean,
     combine_sum,
@@ -145,12 +141,11 @@ def _stacked_callback_shim(cb: Callable) -> Callable:
     mask instead of the raw carry dict, and any ``state``/``hyper``/
     ``active`` swap it returns is folded back into the carry.  Dispatch
     attributes (``order``, ``before_epoch``) are preserved."""
-    import dataclasses as _dc
-
     def shim(env):
         carry = env.state
-        tenv = _dc.replace(env, state=carry["trial"], hyper=carry["hyper"],
-                           active=np.asarray(carry["active"]))
+        tenv = dataclasses.replace(env, state=carry["trial"],
+                                   hyper=carry["hyper"],
+                                   active=np.asarray(carry["active"]))
         out = cb(tenv)
         if not out:
             return None
@@ -260,18 +255,20 @@ class DistributedRunner:
     # ------------------------------------------------------------------ #
     # primitive: one pass over partitions (trace-safe)
     # ------------------------------------------------------------------ #
-    def partition_apply(self, data: jnp.ndarray, fn: Callable,
-                        broadcast: Sequence[Any] = (),
-                        combine: Optional[str] = None) -> Any:
+    def _pass(self, fn: Callable, data: jnp.ndarray,
+              broadcast: Tuple[Any, ...], combine: Optional[str]) -> Any:
         """Run ``fn(block, *broadcast)`` on every partition of ``data``.
 
-        ``combine=None`` returns the stacked per-partition results with a
-        leading ``(num_shards, ...)`` axis; ``"mean" | "sum" | "concat"``
-        combines them across partitions with the configured schedule.
-        Callable inside ``jax.jit`` — algorithms with bespoke outer loops
-        (ALS) build on this directly.
+        The only place the runner tells a mesh from emulated partitions.
+        On a mesh, a ``shard_map`` hands each chip its own rows and the
+        combine is a collective under the configured schedule.  Without
+        one, the rows are split into ``(num_shards, rows, ...)`` blocks and
+        ``_map_partitions`` runs ``fn`` on one block after another, so
+        ``fn`` compiles as it does on one chip of the mesh; the combine is
+        the algebraically-equal local reduction.  ``combine=None`` returns
+        the per-partition results stacked on a leading ``(num_shards,
+        ...)`` axis.
         """
-        broadcast = tuple(broadcast)
         if self.mesh is not None:
             axes = self.data_axes
 
@@ -281,20 +278,32 @@ class DistributedRunner:
                     return jax.tree.map(lambda x: x[None], out)
                 return _COMBINERS[combine](out, axes, self.schedule)
 
-            mapped = shard_map(
+            return shard_map(
                 spmd,
                 mesh=self.mesh,
                 in_specs=(pt.data_spec(axes),) + tuple(P() for _ in broadcast),
                 out_specs=P(axes) if combine is None else P(),
-            )
-            return mapped(data, *broadcast)
+            )(data, *broadcast)
 
         blocks = pt.partition_rows(data, self.num_shards)
-        outs = [fn(blocks[i], *broadcast) for i in range(self.num_shards)]
-        stacked = jax.tree.map(lambda *xs: jnp.stack(xs, axis=0), *outs)
+        stacked = _map_partitions(lambda block: fn(block, *broadcast), blocks)
         if combine is None:
             return stacked
         return _emulated_combine(stacked, combine)
+
+    def partition_apply(self, data: jnp.ndarray, fn: Callable,
+                        broadcast: Sequence[Any] = (),
+                        combine: Optional[str] = None) -> Any:
+        """Run ``fn(block, *broadcast)`` on every partition of ``data``.
+
+        ``combine=None`` returns the stacked per-partition results with a
+        leading ``(num_shards, ...)`` axis; ``"mean" | "sum" | "concat"``
+        combines them across partitions with the configured schedule.
+        Callable inside ``jax.jit`` — algorithms with bespoke outer loops
+        (ALS) build on this directly.  ``fn`` is traced, never run on
+        concrete blocks.
+        """
+        return self._pass(fn, data, tuple(broadcast), combine)
 
     # ------------------------------------------------------------------ #
     # one-shot sufficient-statistics pass
@@ -309,6 +318,56 @@ class DistributedRunner:
     # ------------------------------------------------------------------ #
     # the paper's iterate-and-combine loop
     # ------------------------------------------------------------------ #
+    def _carry(self, state: Any) -> Any:
+        """The carry a compiled loop starts from.
+
+        Replicated onto the mesh (kept as is when emulated or already
+        placed): segmented callers alternate host-built carries (first
+        segment) with device outputs of the previous segment, and without
+        one canonical input sharding the jitted loop compiles TWICE for the
+        same shapes — the exact miss ``repro.analysis.assert_no_retrace``
+        flags on the rung loop.  Under an outer trace the caller governs
+        placement; a staged ``device_put`` would read as a per-step
+        transfer in the jaxpr audit.  When the loop donates its carry, the
+        carry is a private copy, never the caller's buffer (an initial
+        state is typically a params field the caller reuses)."""
+        if self.mesh is not None and jax.core.trace_ctx.is_top_level():
+            sharding = jax.sharding.NamedSharding(self.mesh, P())
+
+            def place(x):
+                if getattr(x, "sharding", None) == sharding:
+                    return x
+                return jax.device_put(x, sharding)
+
+            state = jax.tree.map(place, state)
+        return jax.tree.map(jnp.copy, state) if self.donate else state
+
+    def _loop(self, local_step: LocalStep, upd: UpdateFn, combine: str,
+              chunks: int) -> Callable:
+        """The one loop program, ``(state, data, rounds) -> state``: a
+        ``lax.scan`` over the round ids in ``rounds``.  Round ``r`` runs
+        ``local_step`` on chunk ``r % chunks`` of every partition's rows
+        (the whole partition when ``chunks`` is 1), combines the partials
+        in one pass and applies ``update``.  Round ids are global (epoch ·
+        chunks + chunk), so round-indexed local steps (lr decay, rotating
+        slices) see a monotone counter across epochs."""
+        def loop(state, data, rounds):
+            def round_body(state, r):
+                def local(block, state):
+                    if chunks > 1:
+                        cr = block.shape[0] // chunks
+                        block = jax.lax.dynamic_slice_in_dim(
+                            block, (r % chunks) * cr, cr, axis=0)
+                    return local_step(block, state, r)
+
+                combined = self._pass(local, data, (state,), combine)
+                return upd(state, combined, r), None
+
+            final, _ = jax.lax.scan(round_body, state, rounds)
+            return final
+
+        return loop
+
     def run_rounds(self, table: Any, init_state: Any, local_step: LocalStep,
                    num_rounds: int, *, combine: str = "mean",
                    update: Optional[UpdateFn] = None,
@@ -332,11 +391,7 @@ class DistributedRunner:
         with trace.span("mli.runner.prepare"):
             run = self.rounds_fn(local_step, num_rounds, combine=combine,
                                  update=update, start_round=start_round)
-            init_state = self._canonical_state(init_state)
-            if self.donate:
-                # donate a private copy, never the caller's buffer: init_state
-                # is typically a params field (w_init) the caller may reuse
-                init_state = jax.tree.map(jnp.copy, init_state)
+            init_state = self._carry(init_state)
         with trace.span("mli.runner.launch"):
             t0 = time.perf_counter()
             final = run(init_state, table.data)
@@ -356,54 +411,21 @@ class DistributedRunner:
                   combine: str = "mean", update: Optional[UpdateFn] = None,
                   start_round: int = 0) -> Callable:
         """The jitted program :meth:`run_rounds` drives, ``(state0, data)
-        -> final state``, with the state carry donated when ``donate`` is
-        on.  The table is an argument, never a closed-over constant, so
-        the compiled program does not embed it; public so a caller can
-        lower and compile it from shapes alone.  The function is named
-        ``mli_rounds``: its program is ``jit_mli_rounds`` in a profile."""
-        upd: UpdateFn = update or _default_update
+        -> final state``: the loop program over the whole table as one
+        chunk, its round ids a constant, with the state carry donated when
+        ``donate`` is on.  The table is an argument, never a closed-over
+        constant, so the compiled program does not embed it; public so a
+        caller can lower and compile it from shapes alone.  The function
+        is named ``mli_rounds``: its program is ``jit_mli_rounds`` in a
+        profile."""
+        loop = self._loop(local_step, update or _default_update, combine, 1)
         rounds = jnp.asarray(np.arange(start_round,
                                        start_round + num_rounds,
                                        dtype=np.int32))
-        donate_argnums = (0,) if self.donate else ()
 
-        if self.mesh is not None:
-            axes = self.data_axes
-
-            @partial(jax.jit, donate_argnums=donate_argnums)
-            def mli_rounds(state0, data):
-                def round_body(state, r):
-                    def spmd(block, state):
-                        part = local_step(block, state, r)
-                        return _COMBINERS[combine](part, axes, self.schedule)
-
-                    combined = shard_map(
-                        spmd,
-                        mesh=self.mesh,
-                        in_specs=(pt.data_spec(axes), P()),
-                        out_specs=P(),
-                    )(data, state)
-                    return upd(state, combined, r), None
-
-                final, _ = jax.lax.scan(round_body, state0, rounds)
-                return final
-
-            return mli_rounds
-
-        num_shards = self.num_shards
-
-        @partial(jax.jit, donate_argnums=donate_argnums)
+        @partial(jax.jit, donate_argnums=(0,) if self.donate else ())
         def mli_rounds(state0, data):
-            blocks = pt.partition_rows(data, num_shards)
-
-            def round_body(state, r):
-                parts = _map_partitions(lambda b: local_step(b, state, r),
-                                        blocks)
-                combined = _emulated_combine(parts, combine)
-                return upd(state, combined, r), None
-
-            final, _ = jax.lax.scan(round_body, state0, rounds)
-            return final
+            return loop(state0, data, rounds)
 
         return mli_rounds
 
@@ -418,90 +440,6 @@ class DistributedRunner:
             raise ValueError(
                 f"rows-per-shard {per_shard} must divide into "
                 f"chunks_per_epoch={chunks_per_epoch}")
-
-    def _canonical_state(self, state: Any) -> Any:
-        """Replicate the state carry onto the mesh (no-op when emulated or
-        already placed).  Segmented callers alternate host-built carries
-        (first segment) with device outputs of the previous segment;
-        without one canonical input sharding the jitted epoch compiles
-        TWICE for the same shapes — the exact miss
-        ``repro.analysis.assert_no_retrace`` flags on the rung loop."""
-        if self.mesh is None or not jax.core.trace_ctx.is_top_level():
-            # placement is a host-side concern; under an outer trace the
-            # caller governs placement and a staged device_put would read
-            # as a per-step transfer in the jaxpr audit
-            return state
-        sharding = jax.sharding.NamedSharding(self.mesh, P())
-
-        def place(x):
-            if getattr(x, "sharding", None) == sharding:
-                return x
-            return jax.device_put(x, sharding)
-
-        return jax.tree.map(place, state)
-
-    def _epoch_fn(self, local_step: LocalStep, upd: UpdateFn, combine: str,
-                  chunks: int) -> Callable:
-        """Build the jitted one-epoch function ``(state, window, rounds) ->
-        state``: a ``lax.scan`` over the window's ``chunks`` minibatches.
-        ``rounds`` carries the global round ids (epoch·chunks + chunk), so
-        round-indexed local steps (lr decay, rotating slices) see a
-        monotone counter across epochs and the compiled function is reused
-        for every epoch."""
-        donate = (0,) if self.donate else ()
-
-        if self.mesh is not None:
-            axes = self.data_axes
-
-            def round_body(window, state, r):
-                def spmd(wblock, state, r):
-                    cr = wblock.shape[0] // chunks
-                    c = r % chunks
-                    block = jax.lax.dynamic_slice_in_dim(wblock, c * cr, cr, axis=0)
-                    part = local_step(block, state, r)
-                    return _COMBINERS[combine](part, axes, self.schedule)
-
-                return shard_map(
-                    spmd,
-                    mesh=self.mesh,
-                    in_specs=(pt.data_spec(axes), P(), P()),
-                    out_specs=P(),
-                )(window, state, r)
-
-            @partial(jax.jit, donate_argnums=donate)
-            def epoch(state, window, rounds):
-                def body(state, r):
-                    combined = round_body(window, state, r)
-                    return upd(state, combined, r), None
-
-                final, _ = jax.lax.scan(body, state, rounds)
-                return final
-
-            return epoch
-
-        num_shards = self.num_shards
-
-        @partial(jax.jit, donate_argnums=donate)
-        def epoch(state, window, rounds):
-            blocks = pt.partition_rows(window, num_shards)
-            cr = blocks.shape[1] // chunks
-
-            def body(state, r):
-                c = r % chunks
-
-                def part(wblock):
-                    block = jax.lax.dynamic_slice_in_dim(wblock, c * cr, cr,
-                                                         axis=0)
-                    return local_step(block, state, r)
-
-                parts = _map_partitions(part, blocks)
-                combined = _emulated_combine(parts, combine)
-                return upd(state, combined, r), None
-
-            final, _ = jax.lax.scan(body, state, rounds)
-            return final
-
-        return epoch
 
     def epoch_fn(self, local_step: LocalStep,
                  update: Optional[UpdateFn] = None, *,
@@ -518,11 +456,15 @@ class DistributedRunner:
         if chunks < 1:
             raise ValueError(f"chunks_per_epoch must be >= 1, got {chunks}")
         cache_key = (local_step, upd, combine, chunks)
-        fn = self._epoch_cache.get(cache_key)
-        if fn is None:
-            fn = self._epoch_fn(local_step, upd, combine, chunks)
-            self._cache_put(cache_key, fn)
-        return fn
+        if cache_key not in self._epoch_cache:
+            loop = self._loop(local_step, upd, combine, chunks)
+
+            @partial(jax.jit, donate_argnums=(0,) if self.donate else ())
+            def epoch(state, window, rounds):
+                return loop(state, window, rounds)
+
+            self._cache_put(cache_key, epoch)
+        return self._epoch_cache[cache_key]
 
     def run_epochs(self, stream: Iterator, init_state: Any,
                    local_step: LocalStep, num_epochs: int, *,
@@ -569,14 +511,10 @@ class DistributedRunner:
 
         before = after = ()
         if callbacks:
-            from repro.tune.callback import (CallbackEnv, EarlyStopException,
-                                             fire_callbacks, split_callbacks)
+            from repro.tune.callback import CallbackEnv, split_callbacks
             before, after = split_callbacks(callbacks)
 
-        state = self._canonical_state(init_state)
-        if self.donate:
-            # donate a private copy, never the caller's buffer
-            state = jax.tree.map(jnp.copy, state)
+        state = self._carry(init_state)
 
         last_saved = None
         rows = None
@@ -587,22 +525,10 @@ class DistributedRunner:
                 env = CallbackEnv(epoch=e, begin_epoch=start_epoch,
                                   end_epoch=num_epochs, round=e * chunks,
                                   state=state)
-                try:
-                    swaps = fire_callbacks(before, env)
-                except EarlyStopException:
+                state, stop = self._fire(before, env, state)
+                if stop:
                     done = e
                     break
-                if set(swaps) - {"state"}:
-                    raise ValueError(
-                        f"run_epochs carries only 'state' — a callback "
-                        f"returned {sorted(set(swaps) - {'state'})} (hyper/"
-                        f"active swaps need the stacked loop)")
-                if "state" in swaps:
-                    # swapped states come from host callbacks: re-place them
-                    # so the compiled epoch's input sharding never drifts
-                    state = self._canonical_state(swaps["state"])
-                    if self.donate:
-                        state = jax.tree.map(jnp.copy, state)
             batch = next(stream)
             window = batch["data"] if isinstance(batch, dict) else batch
             self._check_window(window, chunks)
@@ -619,22 +545,7 @@ class DistributedRunner:
                 env = CallbackEnv(epoch=done, begin_epoch=start_epoch,
                                   end_epoch=num_epochs, round=done * chunks,
                                   state=state, evals=evals)
-                try:
-                    swaps = fire_callbacks(after, env)
-                except EarlyStopException:
-                    stopped = True
-                    swaps = {}
-                if set(swaps) - {"state"}:
-                    raise ValueError(
-                        f"run_epochs carries only 'state' — a callback "
-                        f"returned {sorted(set(swaps) - {'state'})} (hyper/"
-                        f"active swaps need the stacked loop)")
-                if "state" in swaps:
-                    # swapped states come from host callbacks: re-place them
-                    # so the compiled epoch's input sharding never drifts
-                    state = self._canonical_state(swaps["state"])
-                    if self.donate:
-                        state = jax.tree.map(jnp.copy, state)
+                state, stopped = self._fire(after, env, state)
             if checkpoint is not None and done % checkpoint.every_epochs == 0:
                 self._save_snapshot(checkpoint, stream, state, done, chunks,
                                     rng, rows=rows)
@@ -645,6 +556,28 @@ class DistributedRunner:
             self._save_snapshot(checkpoint, stream, state, done, chunks,
                                 rng, rows=rows)
         return state
+
+    def _fire(self, hooks: Sequence[Callable], env: Any,
+              state: Any) -> Tuple[Any, bool]:
+        """Fire host callbacks between two epochs of :meth:`run_epochs`.
+        Returns the state the next epoch trains on (a callback may swap
+        it) and whether a callback asked the loop to stop."""
+        from repro.tune.callback import EarlyStopException, fire_callbacks
+
+        try:
+            swaps = fire_callbacks(hooks, env)
+        except EarlyStopException:
+            return state, True
+        if set(swaps) - {"state"}:
+            raise ValueError(
+                f"run_epochs carries only 'state' — a callback "
+                f"returned {sorted(set(swaps) - {'state'})} (hyper/"
+                f"active swaps need the stacked loop)")
+        if "state" in swaps:
+            # swapped states come from host callbacks: re-place them so the
+            # compiled epoch's input sharding never drifts
+            state = self._carry(swaps["state"])
+        return state, False
 
     def _save_snapshot(self, policy: CheckpointPolicy, stream: Any, state: Any,
                        epoch: int, chunks: int, rng: Optional[jnp.ndarray], *,
@@ -657,7 +590,10 @@ class DistributedRunner:
             # replicated state, process 0 persists it (shared filesystem);
             # the SSP exchange lane never reaches here multi-process — its
             # hosts are independent single-process programs with their own
-            # checkpoint dirs.
+            # checkpoint dirs.  The other hosts still wait for the state, so
+            # none runs ahead of the writer's snapshot: a host lost at the
+            # next window leaves this snapshot behind for the restart.
+            jax.block_until_ready(state)
             return
         stream_step = getattr(stream, "step", None)
         if stream_step is None:
@@ -690,6 +626,33 @@ class DistributedRunner:
         save_checkpoint(policy.ckpt_dir, epoch, tree, metadata=meta,
                         keep=policy.keep)
 
+    def _restore(self, ckpt_dir: str, stream: Any, template: Any,
+                 step: Optional[int], checkpoint: Optional[CheckpointPolicy],
+                 writer: str) -> Tuple[Any, dict, Any, int,
+                                       Optional[CheckpointPolicy]]:
+        """The restore both resume paths share: the snapshot's tree and
+        metadata, the stream sought to its position, the rng key, the
+        epoch, and the checkpoint policy (rebuilt from the metadata when
+        the caller passes none).  ``writer`` names the loop that writes
+        such snapshots, for the error a snapshot without metadata raises."""
+        from repro.checkpoint.store import restore_with_metadata
+
+        state, ck_step, meta = restore_with_metadata(ckpt_dir, template, step)
+        if meta is None:
+            raise ValueError(
+                f"checkpoint step {ck_step} under {ckpt_dir} carries no "
+                f"resume metadata — was it written by {writer}?")
+        if not hasattr(stream, "seek"):
+            raise TypeError("resume requires a seekable stream "
+                            "(BatchIterator or anything with .seek(step))")
+        stream.seek(meta["stream_step"])
+        rng = (jnp.asarray(meta["rng"], jnp.uint32)
+               if meta.get("rng") is not None else None)
+        if checkpoint is None and meta.get("every_epochs"):
+            checkpoint = CheckpointPolicy(ckpt_dir, meta["every_epochs"],
+                                          meta.get("keep"))
+        return state, meta, rng, int(meta["epoch"]), checkpoint
+
     def resume(self, ckpt_dir: str, stream: Any, init_state: Any,
                local_step: LocalStep, num_epochs: int, *,
                combine: str = "mean", update: Optional[UpdateFn] = None,
@@ -718,8 +681,7 @@ class DistributedRunner:
         state pytree itself is layout-free (combines produce replicated
         trees), so only the stream's row partitioning changes.
         """
-        from repro.checkpoint.store import load_metadata, \
-            restore_with_metadata
+        from repro.checkpoint.store import load_metadata
 
         peek = load_metadata(ckpt_dir, step) or {}
         wrapped = bool(peek.get("wrapped"))
@@ -731,16 +693,13 @@ class DistributedRunner:
                     f"state — resume needs the CheckpointPolicy with an "
                     f"extra_state template to restore it atomically")
             template = {"state": init_state, "extra": checkpoint.extra_state}
-        state, ck_step, meta = restore_with_metadata(ckpt_dir, template, step)
+        state, meta, rng, epoch, checkpoint = self._restore(
+            ckpt_dir, stream, template, step, checkpoint, "run_epochs")
         if wrapped:
             # hand the restored extra tree back through the policy (and
             # keep re-saving it with every later snapshot)
             checkpoint.extra_state = state["extra"]
             state = state["state"]
-        if meta is None:
-            raise ValueError(
-                f"checkpoint step {ck_step} under {ckpt_dir} carries no "
-                f"resume metadata — was it written by run_epochs?")
         for name, have in (("schedule", self.schedule.value),
                            ("num_shards", self.num_shards)):
             want = meta.get(name)
@@ -750,8 +709,8 @@ class DistributedRunner:
                 rows = meta.get("rows_per_epoch")
                 if rows:
                     # validates the new layout and quantifies the motion;
-                    # raises before any state is touched when the rows
-                    # cannot split evenly over the surviving shards
+                    # raises before any epoch runs when the rows cannot
+                    # split evenly over the surviving shards
                     pt.plan_resize(int(rows), int(want), int(have))
                 continue
             raise ValueError(
@@ -765,16 +724,6 @@ class DistributedRunner:
             raise ValueError(
                 f"cannot resume: checkpoint used chunks_per_epoch={chunks}, "
                 f"got {chunks_per_epoch}")
-        if not hasattr(stream, "seek"):
-            raise TypeError("resume requires a seekable stream "
-                            "(BatchIterator or anything with .seek(step))")
-        stream.seek(meta["stream_step"])
-        rng = (jnp.asarray(meta["rng"], jnp.uint32)
-               if meta.get("rng") is not None else None)
-        epoch = int(meta["epoch"])
-        if checkpoint is None and meta.get("every_epochs"):
-            checkpoint = CheckpointPolicy(ckpt_dir, meta["every_epochs"],
-                                          meta.get("keep"))
         if epoch >= num_epochs:
             return state
         return self.run_epochs(stream, state, local_step, num_epochs,
@@ -896,13 +845,8 @@ class DistributedRunner:
             raise ValueError(f"SSP lane supports combine='mean'|'sum', "
                              f"got {combine!r}")
 
-        epoch_fn = None
-        if not stats_lane:
-            cache_key = (local_step, upd, combine, chunks)
-            epoch_fn = self._epoch_cache.get(cache_key)
-            if epoch_fn is None:
-                epoch_fn = self._epoch_fn(local_step, upd, combine, chunks)
-                self._cache_put(cache_key, epoch_fn)
+        epoch_fn = None if stats_lane else self.epoch_fn(
+            local_step, update, combine=combine, chunks_per_epoch=chunks)
 
         state = init_state
         if self.donate and not stats_lane:
@@ -981,26 +925,11 @@ class DistributedRunner:
         byte-identical, and the clocks re-converge.  ``staleness`` defaults
         to the checkpointed value.
         """
-        from repro.checkpoint.store import restore_with_metadata
-
-        state, ck_step, meta = restore_with_metadata(ckpt_dir, init_state, step)
-        if meta is None:
-            raise ValueError(
-                f"checkpoint step {ck_step} under {ckpt_dir} carries no "
-                f"resume metadata — was it written by run_epochs_ssp?")
+        state, meta, rng, epoch, checkpoint = self._restore(
+            ckpt_dir, stream, init_state, step, checkpoint, "run_epochs_ssp")
         if staleness is None:
             staleness = int(meta.get("staleness", 0))
         chunks = int(meta.get("chunks_per_epoch", 1))
-        if not hasattr(stream, "seek"):
-            raise TypeError("resume requires a seekable stream "
-                            "(BatchIterator or anything with .seek(step))")
-        stream.seek(meta["stream_step"])
-        rng = (jnp.asarray(meta["rng"], jnp.uint32)
-               if meta.get("rng") is not None else None)
-        epoch = int(meta["epoch"])
-        if checkpoint is None and meta.get("every_epochs"):
-            checkpoint = CheckpointPolicy(ckpt_dir, meta["every_epochs"],
-                                          meta.get("keep"))
         if epoch >= num_epochs:
             return state
         return self.run_epochs_ssp(

@@ -233,15 +233,30 @@ def _assert_rel(got, want, tol=1e-6):
     assert err <= tol, err
 
 
-@pytest.mark.parametrize("combine", ["mean", "sum"])
+def _block_pass(block, w):
+    """A one-shot pass with a per-row and a per-column result, so that every
+    combine (concat included) has something to join."""
+    return {"margin": block[:, 1:] @ w, "colsum": jnp.sum(block, axis=0)}
+
+
+# each combine written in NumPy over the (partitions, ...) stacked results
+_PLAIN_COMBINES = {
+    None: lambda x: x,
+    "mean": lambda x: np.mean(x, axis=0),
+    "sum": lambda x: np.sum(x, axis=0),
+    "concat": lambda x: x.reshape((-1,) + x.shape[2:]),
+}
+
+
+@pytest.mark.parametrize("combine", ["mean", "sum", None, "concat"])
 @pytest.mark.parametrize("num_shards", [1, 2, 4])
 def test_emulated_partitions_match_plain_loop(rng, num_shards, combine):
-    """run_rounds and epoch_fn on emulated partitions equal a plain loop
-    over the blocks of partition_rows, and each launch of run_rounds counts
-    one partition pass per partition per round."""
+    """partition_apply, run_rounds and epoch_fn on emulated partitions equal
+    a plain loop over the blocks of partition_rows, and each launch of
+    run_rounds counts one partition pass per partition per round.  The
+    rounds take the combines of an iterative method, mean and sum."""
     from repro.core import trace
 
-    local_step, update = _LOCAL_PASSES[combine]
     X = np.asarray(rng.normal(size=(32, 4)), np.float32)
     y = (X @ np.asarray(rng.normal(size=4), np.float32) > 0).astype(np.float32)
     data = np.concatenate([y[:, None], X], 1)
@@ -250,6 +265,17 @@ def test_emulated_partitions_match_plain_loop(rng, num_shards, combine):
     t = MLNumericTable.from_numpy(data, num_shards=num_shards)
     runner = DistributedRunner.for_table(t)
 
+    got = runner.partition_apply(t.data, _block_pass, (jnp.asarray(w0),),
+                                 combine=combine)
+    parts = [jax.tree.map(np.asarray, _block_pass(b, w0)) for b in blocks]
+    stacked = jax.tree.map(lambda *xs: np.stack(xs), *parts)
+    want = jax.tree.map(_PLAIN_COMBINES[combine], stacked)
+    assert jax.tree.map(np.shape, got) == jax.tree.map(np.shape, want)
+    jax.tree.map(_assert_rel, got, want)
+    if combine not in _LOCAL_PASSES:
+        return
+
+    local_step, update = _LOCAL_PASSES[combine]
     before = trace.snapshot().get("runner.partition_passes", 0)
     got = runner.run_rounds(t, jnp.asarray(w0), local_step, 3,
                             combine=combine, update=update)
